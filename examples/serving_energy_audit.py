@@ -34,7 +34,7 @@ def main():
     print(f"served {len(requests)} requests / {toks} tokens "
           f"in {dt:.1f}s ({toks/dt:.1f} tok/s)")
 
-    print("\n=== Magneton audit of the decode step ===")
+    print("\n=== Magneton audit of the prompt pass's LM head ===")
     report = engine.energy_report(prompt_len=16)
     print(report.render())
 

@@ -32,6 +32,7 @@ import json
 import sys
 from pathlib import Path
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.artifact import (ArtifactStore, ArtifactValueError,
                                  CandidateArtifact)
 from repro.core.energy import backend_from_name
@@ -692,4 +693,5 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -30,7 +30,7 @@ import numpy as np
 from repro.core import costs as costs_mod
 from repro.core import hlo_costs as hlo_costs_mod
 from repro.core.graph import OpGraph
-from repro.hw.specs import CPU_HOST, TPU_V5E, HardwareSpec
+from repro.hw.specs import TPU_V5E, HardwareSpec, local_device_spec
 
 
 @dataclasses.dataclass
@@ -176,14 +176,14 @@ class ReplayProfiler:
 
     The paper's fallback when no power meter is attached: replay each operator
     long enough to average out sampling noise, then convert time to energy via
-    the power model.  On this host the measurement is real CPU time; the power
-    conversion uses the host spec so analytic and measured Joules live on the
-    same scale.
+    the power model.  The time is measured on the device this process runs
+    on, so by default it is priced through that device's spec
+    (``hw.specs.DEVICE_SPECS``); an unknown device raises.
     """
 
-    def __init__(self, spec: HardwareSpec = CPU_HOST,
+    def __init__(self, spec: HardwareSpec | None = None,
                  min_replay_time_s: float = 5e-3, max_replay_iters: int = 64):
-        self.spec = spec
+        self.spec = spec if spec is not None else local_device_spec()
         self.min_replay_time_s = min_replay_time_s
         self.max_replay_iters = max_replay_iters
 
@@ -280,10 +280,11 @@ class AnalyticalBackend:
 
 @dataclasses.dataclass(frozen=True)
 class ReplayBackend:
-    """Replay-measured wall time on the host, converted through its power
-    model (the paper's software-profiling fallback)."""
+    """Replay-measured wall time on the local device, converted through its
+    power model (the paper's software-profiling fallback).  The spec defaults
+    to the local device's entry in ``hw.specs.DEVICE_SPECS``."""
 
-    spec: HardwareSpec = CPU_HOST
+    spec: HardwareSpec = dataclasses.field(default_factory=local_device_spec)
     min_replay_time_s: float = 5e-3
     max_replay_iters: int = 64
 

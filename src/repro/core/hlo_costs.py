@@ -23,21 +23,31 @@ Two layers:
   cost breakdown:
 
   - each instruction's FLOPs / transcendentals / bytes are computed from its
-    opcode and printed operand/result shapes and credited to the jaxpr
-    equation named in its metadata;
-  - a fusion's HBM traffic is its operands + results (interior values never
-    touch HBM); when the fusion merges instructions from several equations
-    the traffic is split proportionally over those equations' interior
+    opcode and its operand/result shapes (operands printed by name only are
+    resolved from their producers) and credited to the jaxpr equation named
+    in its metadata; bytes count HBM only — a value whose layout names
+    another memory space (``S(1)``, the TPU's on-chip memory) moves none;
+  - a fusion's HBM traffic is what it reads (each parameter whole, or only
+    the slices it takes of one, as a scan body does of stacked weights)
+    plus its results; interior values, nested fusions included, never touch
+    HBM.  When the fusion merges instructions from several equations the
+    traffic is split proportionally over those equations' interior
     footprints — the *only* place a proportional split happens;
-  - while bodies are multiplied by XLA's ``known_trip_count`` (fixing the
-    cost_analysis scan undercount), and collectives inside them are credited
-    to the owning scan equation per iteration;
+  - while bodies are multiplied by XLA's ``known_trip_count``, or where the
+    compiler prints none (the TPU's) by the count its condition and initial
+    tuple spell out (fixing the cost_analysis scan undercount), and
+    collectives inside them are credited to the owning scan equation per
+    iteration;
+  - a convolution (the TPU compiler's form of every dot) is priced from its
+    ``dim_labels`` and window: the multiply-adds that land on real input,
+    not on padding or dilation holes;
   - opcodes whose cost the HLO text does not expose (custom-call — Pallas
-    interpret callbacks, TopK, FFT —, convolution, sort, conditional) fall
+    kernels and interpret callbacks, TopK, FFT —, sort, conditional) fall
     back to the *analytic* rule for the equation they map to;
-  - XLA-introduced instructions with no provenance (tuple plumbing copies,
-    layout ops) land in a residual bucket that is distributed proportionally
-    over the attributed columns.
+  - XLA-introduced instructions with no provenance (weight prefetch, layout
+    copies) are charged to the first equation their result flows into; what
+    reaches none lands in a residual bucket that is distributed
+    proportionally over the attributed columns.
 
   shard_map bodies cannot be replayed equation-by-equation outside their mesh
   context, so the whole region is bound under a ``maggrp<i>_<j>`` scope and
@@ -277,38 +287,58 @@ _COMP_HEADER_RE = re.compile(r"^(ENTRY\s+)?%([\w.\-]+)\s*\(.*\{\s*$")
 _INSTR_RE = re.compile(r"^\s+(?:ROOT\s+)?%([\w.\-]+)\s+=\s+(.*)$")
 
 
+# a typed shape with its layout; ``S(n)`` in the layout names a memory
+# space other than HBM (the TPU's on-chip memories)
+_TYPED_SHAPE_RE = re.compile(r"\b([a-z0-9]+)\[([0-9,]*)\](\{[^}]*\})?")
+
+
 @dataclasses.dataclass
 class HloInstruction:
     name: str
     opcode: str
     line: str                                   # full raw text (attrs)
-    shapes_out: list[tuple[str, int]]           # (dtype, numel)
-    shapes_in: list[tuple[str, int]]
+    shapes_out: list[tuple[str, int, bool]]     # (dtype, numel, in HBM)
+    shapes_in: list[tuple[str, int, bool]]
     op_name: str
     trip: int | None
+    operands: list[str] = dataclasses.field(default_factory=list)
+    dims_out: list[tuple[int, ...]] = dataclasses.field(default_factory=list)
+    dims_in: list[tuple[int, ...]] = dataclasses.field(default_factory=list)
 
     @property
     def result_numel(self) -> float:
-        return float(sum(n for _, n in self.shapes_out))
+        return float(sum(n for _, n, _ in self.shapes_out))
 
     @property
     def result_bytes(self) -> float:
-        return float(sum(n * _DTYPE_BYTES.get(dt, 4)
-                         for dt, n in self.shapes_out))
+        """HBM bytes of the result (values in on-chip memory excluded)."""
+        return _nbytes(self.shapes_out)
 
     @property
     def operand_bytes(self) -> float:
-        return float(sum(n * _DTYPE_BYTES.get(dt, 4)
-                         for dt, n in self.shapes_in))
+        return _nbytes(self.shapes_in)
+
+    @property
+    def footprint(self) -> float:
+        """Bytes of the result wherever it lives."""
+        return _nbytes(self.shapes_out, hbm_only=False)
 
 
-def _shapes(segment: str) -> list[tuple[str, int]]:
-    out = []
-    for dt, dims in _SHAPE_RE.findall(segment):
-        if dt not in _DTYPE_BYTES:
-            continue
-        out.append((dt, _shape_numel(dims)))
-    return out
+def _nbytes(shapes, hbm_only: bool = True) -> float:
+    return float(sum(n * _DTYPE_BYTES.get(dt, 4) for dt, n, hbm in shapes
+                     if hbm or not hbm_only))
+
+
+def _typed_shapes(segment: str) -> list[tuple[str, tuple[int, ...], bool]]:
+    return [(dt, tuple(int(d) for d in dims.split(",") if d),
+             not re.search(r"S\([1-9]\d*\)", layout or ""))
+            for dt, dims, layout in _TYPED_SHAPE_RE.findall(segment)
+            if dt in _DTYPE_BYTES]
+
+
+def _shapes(segment: str) -> list[tuple[str, int, bool]]:
+    return [(dt, int(np.prod(dims, dtype=np.int64)), hbm)
+            for dt, dims, hbm in _typed_shapes(segment)]
 
 
 def _parse_instruction(line: str) -> HloInstruction | None:
@@ -349,11 +379,33 @@ def _parse_instruction(line: str) -> HloInstruction | None:
     operands = rest[p + 1:end]
     mm = re.search(r"op_name=\"([^\"]*)\"", rest)
     mt = re.search(r"\"known_trip_count\":\{\"n\":\"(\d+)\"\}", rest)
+    # older XLA prints each operand's shape before its name; newer prints
+    # the name only, and parse_hlo_module resolves it from the producer
     return HloInstruction(
         name=name, opcode=opcode, line=rest,
         shapes_out=_shapes(type_str), shapes_in=_shapes(operands),
         op_name=mm.group(1) if mm else "",
-        trip=int(mt.group(1)) if mt else None)
+        trip=int(mt.group(1)) if mt else None,
+        operands=re.findall(r"%([\w.\-]+)", operands),
+        dims_out=[d for _, d, _ in _typed_shapes(type_str)],
+        dims_in=[d for _, d, _ in _typed_shapes(operands)])
+
+
+def _resolve_operands(instrs: list[HloInstruction]) -> None:
+    """Fill operand shapes from the producers' result shapes, for modules
+    that print operands by name only (an operand always names a value of
+    the same computation)."""
+    table = {ins.name: ins for ins in instrs}
+    for ins in instrs:
+        if ins.shapes_in:
+            continue
+        for name in ins.operands:
+            src = table.get(name)
+            if src is not None:
+                ins.shapes_in.extend(src.shapes_out)
+                # one entry per operand; a tuple-typed operand has no dims
+                ins.dims_in.append(src.dims_out[0]
+                                   if len(src.dims_out) == 1 else ())
 
 
 def parse_hlo_module(text: str
@@ -383,7 +435,93 @@ def parse_hlo_module(text: str
         ins = _parse_instruction(line)
         if ins is not None:
             cur.append(ins)
+    for instrs in comps.values():
+        _resolve_operands(instrs)
     return entry, comps
+
+
+def _while_trips(comps: dict[str, list[HloInstruction]], comp: str,
+                 ins: HloInstruction) -> int | None:
+    """Trip count of a counted loop whose ``known_trip_count`` XLA did not
+    print (the TPU compiler prints none): the condition compares one
+    element of the loop tuple with a constant, and the tuple the loop
+    starts from sets that element to a constant (or a copy of one).  None
+    otherwise."""
+    m = re.search(r"condition=%([\w.\-]+)", ins.line)
+    cond = comps.get(m.group(1), []) if m else []
+    here = {i.name: i for i in comps.get(comp, ())}
+    inside = {i.name: i for i in cond}
+    cmps = [i for i in cond if i.opcode == "compare"]
+    if len(cmps) != 1 or len(cmps[0].operands) != 2 or not ins.operands:
+        return None
+    direction = re.search(r"direction=([A-Z]+)", cmps[0].line)
+    counter, bound = (inside.get(n) for n in cmps[0].operands)
+    init = here.get(ins.operands[0])
+    if (direction is None or counter is None or bound is None
+            or init is None or counter.opcode != "get-tuple-element"
+            or bound.opcode != "constant" or init.opcode != "tuple"):
+        return None
+    k = re.search(r"index=(\d+)", counter.line)
+    start = (here.get(init.operands[int(k.group(1))])
+             if k and int(k.group(1)) < len(init.operands) else None)
+    while start is not None and start.opcode in ("copy", "bitcast"):
+        start = here.get(start.operands[0]) if start.operands else None
+    if start is None or start.opcode != "constant":
+        return None
+    try:
+        lo = int(start.line[start.line.find("(") + 1:start.line.find(")")])
+        hi = int(bound.line[bound.line.find("(") + 1:bound.line.find(")")])
+    except ValueError:
+        return None
+    trips = {"LT": hi - lo, "LE": hi - lo + 1,
+             "GT": lo - hi, "GE": lo - hi + 1}.get(direction.group(1))
+    return trips if trips is not None and trips > 0 else None
+
+
+def _fusion_read_bytes(comps: dict[str, list[HloInstruction]],
+                       comp: str) -> float | None:
+    """HBM bytes a fused computation reads: each parameter whole, except a
+    parameter the fusion only slices, directly or in a fusion nested in it
+    (a scan body's slice of its stacked weights), which reads its slices.
+    None when the computation has no parameters to go by."""
+    users: dict[str, dict[str, list[tuple[HloInstruction, int]]]] = {}
+
+    def uses(c: str, name: str):
+        if c not in users:
+            table: dict[str, list[tuple[HloInstruction, int]]] = {}
+            for ins in comps.get(c, ()):
+                for j, operand in enumerate(ins.operands):
+                    table.setdefault(operand, []).append((ins, j))
+            users[c] = table
+        return users[c].get(name, [])
+
+    def params(c: str) -> dict[int, HloInstruction]:
+        out = {}
+        for ins in comps.get(c, ()):
+            m = re.match(r"parameter\((\d+)\)", ins.line)
+            if ins.opcode == "parameter" and m:
+                out[int(m.group(1))] = ins
+        return out
+
+    def read(c: str, prm: HloInstruction) -> float:
+        full = prm.result_bytes
+        used = uses(c, prm.name)
+        total = 0.0
+        for user, j in used:
+            inner = re.search(r"calls=%([\w.\-]+)", user.line)
+            if user.opcode in ("dynamic-slice", "slice", "gather") and j == 0:
+                total += user.footprint
+            elif user.opcode == "fusion" and inner and j in params(
+                    inner.group(1)):
+                total += read(inner.group(1), params(inner.group(1))[j])
+            else:
+                return full
+        return min(total, full) if used else full
+
+    top = params(comp)
+    if not top:
+        return None
+    return sum(read(comp, prm) for prm in top.values())
 
 
 # ---------------------------------------------------------------------------
@@ -397,7 +535,7 @@ _FREE_OPS = {
     # async completion halves: the paired -start op carries the full cost
     "all-reduce-done", "all-gather-done", "reduce-scatter-done",
     "all-to-all-done", "collective-permute-done", "copy-done", "send-done",
-    "recv-done", "async-done", "async-update",
+    "recv-done", "slice-done", "async-done", "async-update",
 }
 _TRANS_OPS = {
     "exponential", "exponential-minus-one", "log", "log-plus-one",
@@ -407,7 +545,7 @@ _TRANS_OPS = {
 # HLO opcodes whose true cost the text does not expose; the equation they
 # attribute to falls back to its analytic operator rule (costs.py).
 _OPAQUE_OPS = {
-    "custom-call", "convolution", "conditional", "sort", "rng",
+    "custom-call", "conditional", "sort", "rng",
     "rng-bit-generator", "rng-get-and-update-state", "fft", "map",
     "triangular-solve", "cholesky", "infeed", "outfeed", "select-and-scatter",
 }
@@ -420,16 +558,66 @@ _COLLECTIVE_OPS.update({f"{k}-start": v for k, v in
 def _dot_flops(ins: HloInstruction) -> float:
     mm = re.search(r"lhs_contracting_dims=\{([0-9,]*)\}", ins.line)
     cdims = [int(d) for d in mm.group(1).split(",") if d] if mm else []
-    # lhs shape: first operand shape inside the operand parens
-    lhs_shape: tuple[int, ...] = ()
-    sm = _SHAPE_RE.search(ins.line[ins.line.find("(") + 1:])
-    if sm is not None:
-        lhs_shape = tuple(int(d) for d in sm.group(2).split(",") if d)
+    lhs_shape = ins.dims_in[0] if ins.dims_in else ()
     k = 1
     for d in cdims:
         if d < len(lhs_shape):
             k *= lhs_shape[d]
     return 2.0 * ins.result_numel * float(max(k, 1))
+
+
+def _conv_flops(ins: HloInstruction) -> float | None:
+    """2 x the multiply-adds of a convolution that land on real input:
+    per spatial dimension, the (output, kernel tap) pairs that read an
+    input element rather than padding or the holes ``lhs_dilate`` makes
+    (the TPU compiler lowers dots, batched dots among them, to padded and
+    dilated convolutions).  None when the instruction does not say
+    enough."""
+    m = re.search(r"dim_labels=([^_\s]+)_([^-\s]+)->([\w]+)", ins.line)
+    if m is None or len(ins.dims_in) < 2 or not ins.dims_out:
+        return None
+    lab_in, lab_k, lab_out = m.groups()
+    lhs, rhs, out = ins.dims_in[0], ins.dims_in[1], ins.dims_out[0]
+    if (len(lab_in) != len(lhs) or len(lab_k) != len(rhs)
+            or len(lab_out) != len(out) or "i" not in lab_k):
+        return None
+    window: dict[str, list[str]] = {}
+    mw = re.search(r"window=\{([^}]*)\}", ins.line)
+    for item in (mw.group(1).split() if mw else ()):
+        key, _, val = item.partition("=")
+        window[key] = val.split("x")
+    ndim = sum(c.isdigit() for c in lab_k)
+
+    def attr(key: str, j: int, default: str) -> str:
+        vals = window.get(key, ())
+        return vals[j] if j < len(vals) else default
+
+    macs = float(rhs[lab_k.index("i")])
+    out_spatial = 1
+    for j in range(ndim):
+        d = str(j)
+        if d not in lab_in or d not in lab_k or d not in lab_out:
+            return None
+        size_in, taps = lhs[lab_in.index(d)], rhs[lab_k.index(d)]
+        size_out = out[lab_out.index(d)]
+        lo = int(attr("pad", j, "0_0").split("_")[0])
+        stride = int(attr("stride", j, "1"))
+        ldil = int(attr("lhs_dilate", j, "1"))
+        rdil = int(attr("rhs_dilate", j, "1"))
+        o = np.arange(size_out)[:, None]
+        k = np.arange(taps)[None, :]
+        pos = o * stride + k * rdil - lo
+        real = (pos >= 0) & (pos <= (size_in - 1) * ldil) & (pos % ldil == 0)
+        macs *= float(real.sum())
+        out_spatial *= size_out
+    return 2.0 * macs * ins.result_numel / max(out_spatial, 1)
+
+
+def _window_read(ins: HloInstruction, window) -> float:
+    """Bytes a slicing instruction reads from HBM: its window, when the
+    source (the first operand) lives there."""
+    src_in_hbm = ins.shapes_in[0][2] if ins.shapes_in else True
+    return _nbytes(window, hbm_only=False) if src_in_hbm else 0.0
 
 
 def _instr_cost(ins: HloInstruction) -> tuple[float, float, float, float]:
@@ -439,31 +627,40 @@ def _instr_cost(ins: HloInstruction) -> tuple[float, float, float, float]:
     io = ins.operand_bytes + ins.result_bytes
     if op == "dot":
         return _dot_flops(ins), 0.0, io, 0.0
+    if op == "convolution":
+        return _conv_flops(ins) or 0.0, 0.0, io, 0.0
     if op in _TRANS_OPS:
         # transcendental ≈ 4 VPU flops/elem (matches costs.py's weighting)
         return 4.0 * out_n, out_n, io, 0.0
     if op in _COLLECTIVE_OPS:
         return 0.0, 0.0, io, ins.result_bytes * _COLLECTIVE_OPS[op]
-    if op in ("copy", "copy-start"):
-        return 0.0, 0.0, 2.0 * ins.result_bytes, 0.0
+    if op == "copy":
+        return 0.0, 0.0, io, 0.0
+    if op == "copy-start":          # result (destination, source, context)
+        return 0.0, 0.0, ins.operand_bytes + _nbytes(ins.shapes_out[:1]), 0.0
+    if op == "slice-start":         # result (source, window, context)
+        k = len(ins.shapes_in)
+        window = ins.shapes_out[k:k + 1]
+        return 0.0, 0.0, _window_read(ins, window) + _nbytes(window), 0.0
     if op == "dynamic-update-slice":
         # in-place window update: read+write the update window only; any
         # buffer duplication XLA inserts shows up as explicit copy instrs
-        upd = (ins.shapes_in[1] if len(ins.shapes_in) > 1
-               else (ins.shapes_out[0] if ins.shapes_out else ("f32", 0)))
-        return 0.0, 0.0, 2.0 * upd[1] * _DTYPE_BYTES.get(upd[0], 4), 0.0
+        upd = (ins.shapes_in[1:2] if len(ins.shapes_in) > 1
+               else ins.shapes_out[:1])
+        return 0.0, 0.0, 2.0 * _nbytes(upd, hbm_only=False), 0.0
     if op == "dynamic-slice":
-        return 0.0, 0.0, 2.0 * ins.result_bytes, 0.0
+        return 0.0, 0.0, (_window_read(ins, ins.shapes_out)
+                          + ins.result_bytes), 0.0
     if op == "gather":
-        idx_b = (ins.shapes_in[1][1] * _DTYPE_BYTES.get(ins.shapes_in[1][0], 4)
-                 if len(ins.shapes_in) > 1 else 0.0)
-        return 0.0, 0.0, 2.0 * ins.result_bytes + idx_b, 0.0
+        idx_b = _nbytes(ins.shapes_in[1:2], hbm_only=False)
+        return 0.0, 0.0, (_window_read(ins, ins.shapes_out)
+                          + ins.result_bytes + idx_b), 0.0
     if op == "scatter":
-        upd = ins.shapes_in[-1] if ins.shapes_in else ("f32", 0)
-        b = upd[1] * _DTYPE_BYTES.get(upd[0], 4)
-        return float(upd[1]), 0.0, 3.0 * b, 0.0
+        upd = ins.shapes_in[-1:]
+        return (float(sum(n for _, n, _ in upd)), 0.0,
+                3.0 * _nbytes(upd, hbm_only=False), 0.0)
     if op in ("reduce", "reduce-window"):
-        return float(sum(n for _, n in ins.shapes_in)), 0.0, io, 0.0
+        return float(sum(n for _, n, _ in ins.shapes_in)), 0.0, io, 0.0
     if op in ("broadcast", "iota"):
         return 0.0, 0.0, io, 0.0
     if op in ("reshape", "transpose", "slice", "concatenate", "pad",
@@ -528,8 +725,8 @@ class PerOpCosts:
         * ``direct_fraction`` — instructions credited straight to their
           originating equation (neither grouped nor residual).
         * ``opaque_nodes`` — nodes priced by their analytic rule because the
-          HLO text hides or distorts their cost (custom-call / conv /
-          pallas emulation).
+          HLO text hides or distorts their cost (custom-call / pallas
+          emulation / a convolution without ``dim_labels``).
         * ``fusion_splits`` — fusions whose HBM traffic was split over
           several genuinely-merged equations (proportional attribution at
           work; artifacts recorded before this counter report 0).
@@ -593,7 +790,8 @@ def attribute_costs(graph, compiled) -> PerOpCosts:
     opaque: set[int] = {i for i, nd in enumerate(graph.nodes)
                         if nd.primitive == "pallas_call"}
     stats = {"instructions": 0, "direct": 0, "grouped": 0,
-             "residual_instrs": 0, "opaque_nodes": 0, "fusion_splits": 0}
+             "residual_instrs": 0, "inherited": 0, "opaque_nodes": 0,
+             "fusion_splits": 0}
 
     def add(tgt, kind: str, amount: float) -> None:
         if amount <= 0.0:
@@ -610,6 +808,33 @@ def attribute_costs(graph, compiled) -> PerOpCosts:
             groups[tgt[1]][kind] += amount
         else:
             residual[kind] += amount
+
+    users: dict[str, dict[str, list[HloInstruction]]] = {}
+
+    def inherited(comp: str, ins: HloInstruction):
+        """Target of a provenance-free instruction: the first equation its
+        result flows into, so XLA's plumbing (weight prefetch, layout
+        copies) is charged to the operator that needs it; residual when
+        the value reaches no attributed instruction."""
+        if comp not in users:
+            table: dict[str, list[HloInstruction]] = {}
+            for other in comps.get(comp, ()):
+                for name in other.operands:
+                    table.setdefault(name, []).append(other)
+            users[comp] = table
+        seen, frontier = {ins.name}, [ins]
+        while frontier:
+            nxt = []
+            for cur in frontier:
+                for user in users[comp].get(cur.name, ()):
+                    tgt = _target(user.op_name)
+                    if tgt[0] != "residual":
+                        return tgt
+                    if user.name not in seen:
+                        seen.add(user.name)
+                        nxt.append(user)
+            frontier = nxt
+        return ("residual", None)
 
     def _called(ins: HloInstruction) -> list[str]:
         if ins.opcode == "fusion":
@@ -634,19 +859,30 @@ def attribute_costs(graph, compiled) -> PerOpCosts:
             if op in _FREE_OPS:
                 continue
             stats["instructions"] += 1
-            tgt = _target(ins.op_name)
+            tgt = own = _target(ins.op_name)
             if tgt[0] == "node":
                 stats["direct"] += 1
             elif tgt[0] == "group":
                 stats["grouped"] += 1
             else:
                 stats["residual_instrs"] += 1
+                tgt = inherited(comp, ins)
+                if tgt[0] != "residual":
+                    stats["inherited"] += 1
+            if op == "fusion" and fusion_weights is not None:
+                # nested in a fusion: its interior is the outer fusion's
+                for c in _called(ins):
+                    walk(c, mult, fusion_weights)
+                continue
             if op == "fusion":
                 called = _called(ins)
                 weights: dict = {}
                 if called:
                     walk(called[0], mult, weights)
-                fus_bytes = (ins.operand_bytes + ins.result_bytes) * mult
+                read = (_fusion_read_bytes(comps, called[0])
+                        if called else None)
+                fus_bytes = ((ins.operand_bytes if read is None else read)
+                             + ins.result_bytes) * mult
                 total_w = sum(weights.values())
                 if total_w > 0:
                     # genuinely merged constituents: proportional split
@@ -659,7 +895,8 @@ def attribute_costs(graph, compiled) -> PerOpCosts:
                     add(tgt, "hbm", fus_bytes)
                 continue
             if op == "while":
-                trips = float(ins.trip or 1)
+                trips = float(ins.trip or _while_trips(comps, comp, ins)
+                              or 1)
                 for c in _called(ins):
                     walk(c, mult * trips, fusion_weights)
                 continue
@@ -667,11 +904,14 @@ def attribute_costs(graph, compiled) -> PerOpCosts:
                 for c in _called(ins):
                     walk(c, mult, fusion_weights)
                 continue
-            if op in _OPAQUE_OPS:
-                if tgt[0] == "node" and 0 <= tgt[1] < n:
-                    opaque.add(tgt[1])
-                elif tgt[0] == "group":
-                    opaque.update(i for i in range(tgt[1][0], tgt[1][1] + 1)
+            if op in _OPAQUE_OPS or (op == "convolution"
+                                     and _conv_flops(ins) is None):
+                # only an equation's own instruction makes it opaque, not
+                # plumbing it inherited
+                if own[0] == "node" and 0 <= own[1] < n:
+                    opaque.add(own[1])
+                elif own[0] == "group":
+                    opaque.update(i for i in range(own[1][0], own[1][1] + 1)
                                   if i < n)
                 continue
             flops, trans, hbm, ici = _instr_cost(ins)
@@ -683,7 +923,7 @@ def attribute_costs(graph, compiled) -> PerOpCosts:
                 # equation's footprint as its share of the fusion's traffic
                 key = tgt if tgt[0] != "residual" else ("residual", None)
                 fusion_weights[key] = (fusion_weights.get(key, 0.0)
-                                       + max(hbm, ins.result_bytes, 1.0))
+                                       + max(hbm, ins.footprint, 1.0))
             else:
                 add(tgt, "hbm", hbm * mult)
 
@@ -704,7 +944,7 @@ def attribute_costs(graph, compiled) -> PerOpCosts:
                 w = np.ones(len(idxs))
             cols[kind][idxs] += amount * w / w.sum()
 
-    # opaque nodes (custom-call / convolution / pallas emulation / ...):
+    # opaque nodes (custom-call / pallas emulation / ...):
     # the HLO text hides or distorts their cost; use the analytic rule.
     # Applied BEFORE the residual distribution so emulation-inflated
     # accumulations cannot skew the residual weights.

@@ -101,9 +101,8 @@ TPU_V5P = HardwareSpec(
     ici_watts=25.0,
 )
 
-# The host this container actually runs on — used by the ReplayProfiler to
-# convert measured wall time into model Joules so analytic and replayed
-# numbers are comparable (benchmarks/bench_energy_accuracy.py).
+# A generic CPU host — prices wall time that the ReplayProfiler measured on
+# a CPU device (DEVICE_SPECS), so analytic and replayed numbers compare.
 CPU_HOST = HardwareSpec(
     name="cpu_host",
     peak_flops_bf16=5e11,
@@ -123,9 +122,29 @@ CPU_HOST = HardwareSpec(
 
 _SPECS = {s.name: s for s in (TPU_V5E, TPU_V5P, CPU_HOST)}
 
+# The spec that prices time measured on a device, keyed by the
+# ``device_kind`` JAX reports for it.  A kind missing here is an error:
+# measured time converted with another device's coefficients is wrong
+# without looking wrong.
+DEVICE_SPECS = {"TPU v5 lite": TPU_V5E, "cpu": CPU_HOST}
+
 
 def get_spec(name: str) -> HardwareSpec:
     try:
         return _SPECS[name]
     except KeyError:
         raise KeyError(f"unknown hardware spec {name!r}; have {sorted(_SPECS)}")
+
+
+def spec_for_device_kind(kind: str) -> HardwareSpec:
+    try:
+        return DEVICE_SPECS[kind]
+    except KeyError:
+        raise KeyError(f"no hardware spec for device kind {kind!r}; "
+                       f"have {sorted(DEVICE_SPECS)}") from None
+
+
+def local_device_spec() -> HardwareSpec:
+    """The spec of the device this process runs on (``jax.devices()[0]``)."""
+    import jax
+    return spec_for_device_kind(jax.devices()[0].device_kind)

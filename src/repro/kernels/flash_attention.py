@@ -11,8 +11,10 @@ O(Sq*Sk + S*d) to O(S*d) — on a 32k prefill that is the difference between
 
 Grid: (batch*heads, num_q_blocks, num_kv_blocks); the kv dimension is the
 minormost (sequential on TPU), so VMEM scratch persists across kv steps.
-MXU alignment: block_q/block_k multiples of 128 in production; d padded to a
-lane multiple by the wrapper (ops.py).
+Blocks: the wrapper (ops.py) picks block_q/block_k that the TPU compiler
+accepts (the whole sequence, or a multiple of the sublane tile) and pads the
+sequence only where no such block divides it; padded keys are masked here
+(``kv_len``).  d is always a whole-dimension block.
 """
 
 from __future__ import annotations
@@ -25,15 +27,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30          # avoids -inf - -inf = nan in the rescale path
 _LANES = 128             # TPU lane width; m/l scratch broadcast over lanes
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   causal: bool, sm_scale: float, block_q: int, block_k: int,
-                  num_kv_blocks: int, q_offset: int):
+                  num_kv_blocks: int, q_offset: int, kv_len: int | None):
     """One (q-block, kv-block) step of the online-softmax recurrence."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -51,6 +51,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     should_run = True
     if causal:
         should_run = ki * block_k <= q_start + block_q - 1
+    if kv_len is not None:                            # all-padding kv block
+        should_run = jnp.logical_and(should_run, ki * block_k < kv_len)
 
     @pl.when(should_run)
     def _body():
@@ -60,12 +62,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         s = jax.lax.dot_general(                       # (block_q, block_k)
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 0)
-            kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                           (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
+        if causal or kv_len is not None:
+            tile = (block_q, block_k)
+            if causal:
+                qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+            kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, tile, 1)
+            keep = qpos >= kpos if causal else None
+            if kv_len is not None:
+                in_len = kpos < kv_len
+                keep = in_len if keep is None else jnp.logical_and(keep, in_len)
+            s = jnp.where(keep, s, NEG_INF)
 
         m_prev = m_ref[:, :1]                          # (block_q, 1)
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -87,11 +93,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        causal: bool = True, sm_scale: float | None = None,
                        block_q: int = 128, block_k: int = 128,
+                       q_offset: int, kv_len: int | None,
                        interpret: bool = False) -> jax.Array:
     """Flat-head flash attention.  q: (BH, Sq, D); k,v: (BH, Sk, D).
 
     GQA head-group mapping is handled by the wrapper (ops.flash_attention),
-    which expands k/v indices; here heads are 1:1.
+    which expands k/v indices; here heads are 1:1.  ``q_offset`` is the
+    causal position of query row 0; ``kv_len`` masks keys at or past it
+    (rows padded by the wrapper), None when no key is padding.
     """
     BH, Sq, D = q.shape
     Sk = k.shape[1]
@@ -102,11 +111,11 @@ def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
     num_q = Sq // block_q
     num_kv = Sk // block_k
     scale = sm_scale if sm_scale is not None else float(1.0 / np.sqrt(D))
-    q_offset = Sk - Sq if causal else 0                # cached-decode offset
 
     kernel = functools.partial(
         _flash_kernel, causal=causal, sm_scale=scale, block_q=block_q,
-        block_k=block_k, num_kv_blocks=num_kv, q_offset=q_offset)
+        block_k=block_k, num_kv_blocks=num_kv, q_offset=q_offset,
+        kv_len=kv_len)
 
     return pl.pallas_call(
         kernel,
@@ -123,7 +132,7 @@ def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -17,8 +17,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 _C = float(np.sqrt(2.0 / np.pi))
 
 
@@ -46,7 +44,7 @@ def _tiled_elementwise(kernel, args, out_dtype, *, block_rows: int,
         in_specs=[spec] * len(args),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, d), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)

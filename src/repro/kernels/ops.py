@@ -1,10 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Every wrapper: (1) normalizes model-layout tensors into the kernel layout,
-(2) picks hardware-aligned block sizes that divide the problem, (3) runs the
-kernel in interpret mode automatically when no TPU is present (CPU test
-containers), and (4) is shape-polymorphic enough for every assigned
-architecture's head_dim / d_ff / state size.
+(2) picks block sizes the TPU compiler accepts (``_pick_block``) and pads
+only where none divides the problem, (3) runs the kernel in interpret mode
+automatically when no TPU is present, and (4) is shape-polymorphic enough
+for every assigned architecture's head_dim / d_ff / state size.
 """
 
 from __future__ import annotations
@@ -25,12 +25,42 @@ def _auto_interpret(interpret: bool | None) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pick_block(size: int, target: int) -> int:
-    """Largest power-of-two divisor of ``size`` that is <= target."""
+def _sublanes(dtype) -> int:
+    """Rows of one (sublane x 128-lane) VMEM tile: 8 for 32-bit, 16 bf16."""
+    return max(8, 32 // jnp.dtype(dtype).itemsize)
+
+
+def _pick_block(size: int, target: int, align: int) -> tuple[int, int]:
+    """(block, padded size) for a second-minor block dimension.
+
+    The TPU compiler takes a block that spans the whole dimension or is a
+    multiple of ``align`` rows.  Take the whole dimension when it fits
+    ``target``; else split it into the fewest blocks of about ``target``
+    rows, each rounded up to ``align``, and pad the dimension to a whole
+    number of blocks (less than ``align`` rows per block).
+    """
+    if size <= target:
+        return size, size
+    n = -(-size // target)
+    block = -(-size // (n * align)) * align
+    return block, n * block
+
+
+def _pow2_divisor(size: int, target: int) -> int:
+    """Largest power-of-two divisor of ``size`` that is <= target (the
+    selective scan's blocks; see ROADMAP B4 for its TPU compile status)."""
     b = 1
     while b * 2 <= min(size, target) and size % (b * 2) == 0:
         b *= 2
     return b
+
+
+def _pad_rows(x: jax.Array, rows: int, axis: int = 0) -> jax.Array:
+    if x.shape[axis] == rows:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, rows - x.shape[axis])
+    return jnp.pad(x, pad)
 
 
 # ---------------------------------------------------------------------------
@@ -42,13 +72,16 @@ def _flash_core(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     """Differentiable core on flat same-head-count tensors (B,H,S,D)."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    o = fa.flash_attention_bh(q.reshape(B * H, Sq, D),
-                              k.reshape(B * H, Sk, D),
-                              v.reshape(B * H, Sk, D),
-                              causal=causal, sm_scale=sm_scale,
-                              block_q=block_q, block_k=block_k,
-                              interpret=interpret)
-    return o.reshape(B, H, Sq, D)
+    bq, sq_pad = _pick_block(Sq, block_q, _sublanes(q.dtype))
+    bk, sk_pad = _pick_block(Sk, block_k, _sublanes(k.dtype))
+    o = fa.flash_attention_bh(
+        _pad_rows(q.reshape(B * H, Sq, D), sq_pad, axis=1),
+        _pad_rows(k.reshape(B * H, Sk, D), sk_pad, axis=1),
+        _pad_rows(v.reshape(B * H, Sk, D), sk_pad, axis=1),
+        causal=causal, sm_scale=sm_scale, block_q=bq, block_k=bk,
+        q_offset=Sk - Sq, kv_len=Sk if sk_pad > Sk else None,
+        interpret=interpret)
+    return o[:, :Sq].reshape(B, H, Sq, D)
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
@@ -88,15 +121,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     KV, Sk = k.shape[1], k.shape[2]
     assert H % KV == 0
     interp = _auto_interpret(interpret)
-    bq = _pick_block(Sq, block_q)
-    bk = _pick_block(Sk, block_k)
     if H != KV:
         reps = H // KV
         head_map = jnp.arange(H, dtype=jnp.int32) // reps
         k = jnp.take(k, head_map, axis=1)
         v = jnp.take(v, head_map, axis=1)
     scale = sm_scale if sm_scale is not None else float(1.0 / np.sqrt(D))
-    return _flash_core(q, k, v, causal, scale, bq, bk, interp)
+    return _flash_core(q, k, v, causal, scale, block_q, block_k, interp)
 
 
 # ---------------------------------------------------------------------------
@@ -111,11 +142,11 @@ def fused_rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-5,
     shape = x.shape
     d = shape[-1]
     rows = int(np.prod(shape[:-1], dtype=np.int64))
-    x2 = x.reshape(rows, d)
-    br = _pick_block(rows, block_rows)
-    out = rmsnorm.rmsnorm_2d(x2, w, eps=eps, block_rows=br,
+    br, padded = _pick_block(rows, block_rows, _sublanes(x.dtype))
+    out = rmsnorm.rmsnorm_2d(_pad_rows(x.reshape(rows, d), padded), w,
+                             eps=eps, block_rows=br,
                              interpret=_auto_interpret(interpret))
-    return out.reshape(shape)
+    return out[:rows].reshape(shape)
 
 
 # ---------------------------------------------------------------------------
@@ -128,11 +159,12 @@ def fused_swiglu(g: jax.Array, u: jax.Array, *, block_rows: int = 256,
     shape = g.shape
     d = shape[-1]
     rows = int(np.prod(shape[:-1], dtype=np.int64))
-    br = _pick_block(rows, block_rows)
-    out = fused_act.swiglu_2d(g.reshape(rows, d), u.reshape(rows, d),
+    br, padded = _pick_block(rows, block_rows, _sublanes(g.dtype))
+    out = fused_act.swiglu_2d(_pad_rows(g.reshape(rows, d), padded),
+                              _pad_rows(u.reshape(rows, d), padded),
                               block_rows=br,
                               interpret=_auto_interpret(interpret))
-    return out.reshape(shape)
+    return out[:rows].reshape(shape)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -141,10 +173,11 @@ def fused_gelu(x: jax.Array, *, block_rows: int = 256,
     shape = x.shape
     d = shape[-1]
     rows = int(np.prod(shape[:-1], dtype=np.int64))
-    br = _pick_block(rows, block_rows)
-    out = fused_act.gelu_2d(x.reshape(rows, d), block_rows=br,
+    br, padded = _pick_block(rows, block_rows, _sublanes(x.dtype))
+    out = fused_act.gelu_2d(_pad_rows(x.reshape(rows, d), padded),
+                            block_rows=br,
                             interpret=_auto_interpret(interpret))
-    return out.reshape(shape)
+    return out[:rows].reshape(shape)
 
 
 # ---------------------------------------------------------------------------
@@ -157,7 +190,7 @@ def fused_ssm_scan(a: jax.Array, b: jax.Array, c: jax.Array, h0: jax.Array,
                    interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
     """a,b: (B,S,di,n); c: (B,S,n); h0: (B,di,n) — all fp32."""
     B, S, di, n = a.shape
-    ck = _pick_block(S, chunk)
-    bd = _pick_block(di, block_d)
+    ck = _pow2_divisor(S, chunk)
+    bd = _pow2_divisor(di, block_d)
     return ssm_scan.ssm_scan_fused(a, b, c, h0, chunk=ck, block_d=bd,
                                    interpret=_auto_interpret(interpret))

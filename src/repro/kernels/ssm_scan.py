@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _ssm_kernel(a_ref, b_ref, c_ref, h0_ref, y_ref, hlast_ref, h_scr, *,
                 chunk: int, num_chunks: int):
@@ -90,7 +88,7 @@ def ssm_scan_fused(a: jax.Array, b: jax.Array, c: jax.Array, h0: jax.Array,
             jax.ShapeDtypeStruct((B, di, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, c, h0)

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import: jax locks the device count on first init.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this produces, from the compiled per-device SPMD module:
@@ -28,6 +24,7 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -338,4 +335,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # jax fixes the host device count when its CPU backend starts, which no
+    # import above does; only the program (never an importer) forces 512
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=512"
+                               ).strip()
     main()

@@ -14,18 +14,20 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
 
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import get_config
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as tf
 from repro.serve.engine import EngineConfig, Request, ServeEngine
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="gpt2-small")
     p.add_argument("--smoke", action="store_true")
@@ -61,7 +63,7 @@ def main() -> None:
                         "alarms fire against the healthy fleet golden")
     p.add_argument("--health-json", action="store_true",
                    help="print engine.health() as JSON after serving")
-    args = p.parse_args()
+    args = p.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -122,18 +124,22 @@ def main() -> None:
 
     if args.energy_audit:
         # error-bounded audit: a broken/hung profiler reports its failure
-        # and leaves the serving results above intact
+        # and leaves the serving results above intact, but the audit that
+        # was asked for did not happen, so the run fails
         report = engine.audit(prompt_len=args.prompt_len,
                               timeout_s=args.audit_timeout)
-        if report is not None:
-            print(report.render())
-        else:
+        if report is None:
             print("energy audit unavailable: "
                   f"{engine.stats.get('audit_last_error', 'breaker open')} "
                   f"(failures={engine.stats['audit_failures']}, "
                   f"timeouts={engine.stats['audit_timeouts']}, "
-                  f"breaker_open={engine.stats['audit_breaker_open']})")
+                  f"breaker_open={engine.stats['audit_breaker_open']})",
+                  file=sys.stderr)
+            return 1
+        print(report.render())
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    enable_compile_cache()
+    sys.exit(main())

@@ -21,6 +21,7 @@ import json
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import SHAPES, ShapeConfig, get_config
 from repro.launch.mesh import make_host_mesh
 from repro.train.loop import LoopConfig, run_training
@@ -109,4 +110,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

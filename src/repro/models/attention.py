@@ -21,6 +21,11 @@ from repro.sharding.rules import constrain
 
 Cache = dict[str, jax.Array]
 
+# attention implementations of the cached path (chunked prefill into a
+# cache, and decode).  The Pallas kernel has no cached form: it takes no
+# cache length mask, so a cached call with it is refused, never rerouted.
+CACHED_ATTN_IMPLS = ("xla", "xla_bf16", "chunked")
+
 
 # ---------------------------------------------------------------------------
 # schemas
@@ -232,6 +237,11 @@ def gqa_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
     new_cache = None
     if cache is not None:
         assert cache_pos is not None
+        if attn_impl not in CACHED_ATTN_IMPLS:
+            raise ValueError(
+                f"attn_impl={attn_impl!r} has no cached attention path "
+                f"(prefill into a cache, decode); use one of "
+                f"{CACHED_ATTN_IMPLS}")
         k_all = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, cache_pos, axis=1)
         v_all = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, cache_pos, axis=1)
         new_cache = {"k": k_all, "v": v_all}

@@ -30,11 +30,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models.layers import ParamSpec, ParamTree
 
-try:  # JAX >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 
 def moe_schema(cfg: ModelConfig) -> ParamTree:
     d, e, f = cfg.d_model, cfg.moe_num_experts, cfg.resolved_moe_d_ff
@@ -180,7 +175,7 @@ def moe_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
     # stays outside under plain GSPMD TP sharding (it is a dense MLP).
     params_routed = {k: v for k, v in params.items() if not k.startswith("shared")}
     cfg_routed = _without_shared(cfg)
-    y, aux = shard_map(lambda p, xl: _shardmap_body(cfg_routed, p, xl, mesh),
+    y, aux = jax.shard_map(lambda p, xl: _shardmap_body(cfg_routed, p, xl, mesh),
                        mesh=mesh, in_specs=(ps, xs), out_specs=(xs, P()),
                        check_vma=False)(params_routed, x)
     if cfg.moe_num_shared:

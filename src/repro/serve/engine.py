@@ -4,8 +4,8 @@ A minimal but real engine: requests enter a queue, are prefilled in batches,
 then decoded together with a shared step counter.  Slot management keeps the
 decode batch full (continuous batching); finished sequences free their slot
 for the next queued request.  The engine exposes an optional Magneton energy
-audit per phase (``energy_report()``) — the paper's profiler as a deployment
-feature.
+audit of its prompt pass (``energy_report()``) — the paper's profiler as a
+deployment feature.
 
 The audit path sits behind an error boundary (:meth:`ServeEngine.audit`):
 a watchdog thread bounds how long an audit may run, every failure is
@@ -31,6 +31,7 @@ from jax.sharding import Mesh
 
 from repro.audit import AuditConfig, EngineAuditor, RequestClass
 from repro.configs.base import ModelConfig
+from repro.models import attention as attn_mod
 from repro.models import transformer as tf
 from repro.serve.serve_step import make_decode_step, make_prefill_step
 
@@ -75,12 +76,21 @@ class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, *, mesh: Mesh | None = None,
                  ecfg: EngineConfig | None = None):
         assert cfg.is_causal, "encoder-only models have no decode path"
-        self.cfg = cfg
-        self.params = params
-        self.mesh = mesh
         # None default: a shared `ecfg=EngineConfig()` dataclass default
         # would alias one mutable config across every engine construction
         self.ecfg = ecfg if ecfg is not None else EngineConfig()
+        # every served step (prefill included) runs attention over a cache
+        if self.ecfg.attn_impl not in attn_mod.CACHED_ATTN_IMPLS:
+            raise ValueError(
+                f"attn_impl={self.ecfg.attn_impl!r} cannot serve: prefill "
+                f"and decode attend over a KV cache, which only "
+                f"{attn_mod.CACHED_ATTN_IMPLS} implement")
+        if mesh is not None:
+            params = jax.device_put(params,
+                                    tf.model_param_shardings(cfg, mesh))
+        self.cfg = cfg
+        self.params = params
+        self.mesh = mesh
         # raw (traceable) prefill kept alongside the jitted one: the live
         # audit probe captures through it so Magneton sees real operators
         self._prefill_fn = make_prefill_step(
@@ -184,38 +194,49 @@ class ServeEngine:
 
     # -- Magneton audit --------------------------------------------------------
     def energy_report(self, *, prompt_len: int = 32, session=None):
-        """Differential energy audit of this engine's decode step against the
-        all-position-logits wasteful twin (hf-38977) — the profiler as a
-        serving feature.
+        """Differential energy audit of this engine's prompt pass against
+        its all-position-logits wasteful twin (hf-38977) — the profiler as
+        a serving feature.
+
+        Both sides are cache-free forward passes of the engine's model and
+        weights over the same ``(batch_size, prompt_len)`` prompt, not the
+        served prefill step (which also writes the KV cache).  The
+        efficient side computes the LM head for the last position only;
+        the twin returns every position's logits from the model, as a
+        model that hands them to its sampler does, and keeps the last.
+        The one difference is the (prompt_len - 1) x vocab redundant
+        logits.  ``optimization_barrier`` marks where the twin's model
+        call ends: without it XLA slices before the LM-head dot and
+        compiles the twin to the efficient program, leaving nothing for an
+        HLO-priced audit to find.
 
         Runs on the Session/artifact API: pass a store-backed
-        :class:`repro.core.session.Session` to persist the decode-step
-        capture and make repeated audits of an unchanged engine cache hits.
+        :class:`repro.core.session.Session` to persist the captures and
+        make repeated audits of an unchanged engine cache hits.
         """
         from repro.core.session import Session
         cfg = self.cfg
         B = self.ecfg.batch_size
         key = jax.random.key(0)
         tokens = jax.random.randint(key, (B, prompt_len), 0, cfg.vocab_size)
-        _, caches = self._prefill(self.params, tokens, None)
+        img = None
+        if cfg.family == "vlm":
+            img = jnp.zeros((B, cfg.num_image_tokens, cfg.d_model),
+                            jnp.dtype(cfg.dtype))
 
-        def efficient(tok):
-            logits, _ = tf.decode_step(cfg, self.params, caches, tok,
-                                       jnp.int32(prompt_len))
-            return logits.astype(jnp.float32)
+        def logits(toks, mode):
+            out, _ = tf.forward(cfg, self.params, toks, image_embeds=img,
+                                remat=False, attn_impl=self.ecfg.attn_impl,
+                                logits_mode=mode)
+            if mode == "all":
+                out = jax.lax.optimization_barrier(out)
+            return out[:, -1:].astype(jnp.float32)
 
-        def wasteful(tok):
-            # recompute the hidden for the last position but pay an
-            # all-positions LM head (vocab x prompt_len redundant logits)
-            logits, _ = tf.decode_step(cfg, self.params, caches, tok,
-                                       jnp.int32(prompt_len))
-            pad = jnp.broadcast_to(logits, (B, prompt_len, cfg.vocab_size))
-            return pad[:, -1:, :].astype(jnp.float32)
-
-        tok = jnp.zeros((B, 1), jnp.int32)
         session = session or Session()
-        art_waste = session.capture(wasteful, (tok,), name="lmhead-all")
-        art_eff = session.capture(efficient, (tok,), name="lmhead-last")
+        art_waste = session.capture(lambda t: logits(t, "all"), (tokens,),
+                                    name="lmhead-all")
+        art_eff = session.capture(lambda t: logits(t, "last"), (tokens,),
+                                  name="lmhead-last")
         return session.compare(art_waste, art_eff)
 
     def audit(self, *, prompt_len: int = 32, session=None,

@@ -177,11 +177,10 @@ class SyncInLoop(Mutation):
 
     @staticmethod
     def _all_reduce(x):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh
         from jax.sharding import PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
-        return shard_map(lambda y: jax.lax.psum(y, "dp"), mesh=mesh,
+        return jax.shard_map(lambda y: jax.lax.psum(y, "dp"), mesh=mesh,
                          in_specs=P(), out_specs=P())(x)
 
     def rewrite(self, eqn, invals):

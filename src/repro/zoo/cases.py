@@ -399,9 +399,8 @@ def _fake_all_reduce(x):
     so the jaxpr carries a genuine collective.
     """
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
-    return shard_map(lambda y: jax.lax.psum(y, "dp"), mesh=mesh,
+    return jax.shard_map(lambda y: jax.lax.psum(y, "dp"), mesh=mesh,
                      in_specs=P(), out_specs=P())(x)
 
 

@@ -16,7 +16,7 @@ actual cause:
 
 * ``KNOWN_SIGN_DISAGREEMENTS`` (2 entries) — the compiled module prices the
   analytically-wasteful side strictly CHEAPER: a true sign flip;
-* ``KNOWN_COMPILER_ERASED`` (4 entries) — XLA compiles both twins to
+* ``KNOWN_COMPILER_ERASED`` (3 entries) — XLA compiles both twins to
   (near-)identical traffic, so the operator-level waste vanishes as a tie.
   The old redistribution could never produce a tie (it preserved analytic
   ratios), which is exactly why two of its five documented "disagreements"
@@ -25,8 +25,10 @@ actual cause:
   (the Pallas interpret-mode emulation no longer pollutes the pricing —
   pallas_call nodes are opaque and take their analytic single-pass rule).
 
-Measured on this container (jax CPU, TPU-v5e spec): 13/19 detect cases
-agree; 2 flip; 4 erase.
+Measured on this container (jax CPU, TPU-v5e spec): 14/19 detect cases
+agree; 2 flip; 3 erase.  c10-addmm left the erased ledger when operand
+shapes that newer XLA prints by name only were resolved from their
+producers: its compiled totals no longer tie.
 """
 
 import pytest
@@ -55,9 +57,6 @@ KNOWN_COMPILER_ERASED = {
     "c5-layout": "algebraic simplification deletes the inverse transpose "
                  "pair entirely — both twins compile to the identical "
                  "bitcast + dot module",
-    "c10-addmm": "both twins compile to the same f32-accumulating dot with "
-                 "an add/convert epilogue fusion; module byte totals tie to "
-                 "within 0.03% (the matched region still agrees)",
     "c16-count-nonzero": "XLA materializes a full-width 4-byte indicator on "
                          "BOTH twins (f32 select vs s32 convert of the "
                          "pred) before the partitioned reduce, so the "

@@ -36,6 +36,9 @@ def _rand(key, shape, dt):
     (1, 8, 1, 256, 16, jnp.float32, True),      # MQA long
     (2, 4, 4, 96, 48, jnp.float32, True),       # non-pow2 seq (block fallback)
     (1, 4, 2, 64, 32, jnp.bfloat16, False),     # GQA bf16 full
+    (1, 2, 2, 12, 64, jnp.bfloat16, True),      # short prompt: whole-seq block
+    (1, 2, 2, 130, 32, jnp.float32, True),      # no aligned divisor: padded
+    (1, 2, 2, 130, 32, jnp.float32, False),     # padded keys masked
 ])
 def test_flash_attention_matches_oracle(B, H, KV, S, D, dtype, causal):
     q = _rand(KEYS[0], (B, H, S, D), dtype)
@@ -94,7 +97,23 @@ def test_flash_attention_property(b, kv_pow, s_pow, causal):
 # rmsnorm
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape", [(8, 64), (3, 5, 128), (2, 7, 9, 256)])
+@pytest.mark.parametrize("size,target,align", [
+    (12, 128, 8), (12, 256, 16), (96, 64, 8), (1024, 128, 16), (130, 128, 8),
+    (303, 256, 16), (24, 16, 16), (1032, 128, 8)])
+def test_pick_block_is_legal_on_tpu(size, target, align):
+    """A block spans the whole dimension or is a multiple of the sublane
+    tile; a dimension longer than the target splits into the fewest
+    blocks of about target rows, padded by less than a tile per block."""
+    block, padded = ops._pick_block(size, target, align)
+    assert block == size or (block % align == 0 and block <= target)
+    assert padded % block == 0 and padded >= size
+    blocks = padded // block
+    assert blocks == -(-size // target)
+    assert padded - size < blocks * align
+
+
+@pytest.mark.parametrize("shape", [(8, 64), (3, 5, 128), (2, 7, 9, 256),
+                                   (3, 101, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rmsnorm_matches_oracle(shape, dtype):
     x = _rand(KEYS[0], shape, dtype)
@@ -131,7 +150,8 @@ def test_swiglu_matches_oracle(shape, dtype):
         atol=_tol(dtype), rtol=_tol(dtype))
 
 
-@pytest.mark.parametrize("shape", [(16, 64), (4, 100, 256), (1, 1, 8)])
+@pytest.mark.parametrize("shape", [(16, 64), (4, 100, 256), (1, 1, 8),
+                                   (3, 101, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gelu_matches_oracle(shape, dtype):
     x = _rand(KEYS[2], shape, dtype)

@@ -1,5 +1,7 @@
 """Serve engine: continuous batching, slot reuse, stats, decode parity."""
 
+import importlib.util
+import os
 import time
 
 import jax
@@ -10,6 +12,8 @@ from repro import configs
 from repro.core.faults import SimulatedCrash
 from repro.models import transformer as tf
 from repro.serve.engine import EngineConfig, Request, ServeEngine
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -132,3 +136,81 @@ def test_serving_survives_force_killed_audit(engine, monkeypatch):
     assert eng.stats["audit_breaker_open"]     # still open, serving unharmed
     assert eng.audit() is None
     assert eng.stats["audit_skipped"] == 1
+
+
+def test_energy_report_names_the_all_position_lm_head(engine):
+    """The HLO-priced audit of the prompt pass finds its waste in the region
+    that holds the all-position LM-head dot, and nowhere else."""
+    from repro.core.energy import HloCostBackend
+    from repro.core.session import Session
+    from repro.hw.specs import CPU_HOST
+    cfg, eng = engine
+    captured = {}
+
+    class Recording(Session):
+        def capture(self, *a, **kw):
+            art = super().capture(*a, **kw)
+            captured[art.name] = art
+            return art
+
+    report = eng.energy_report(session=Recording(
+        backend=HloCostBackend(CPU_HOST), allow_degraded=False))
+    assert not report.is_degraded
+    graph = captured["lmhead-all"].graph
+    heads = {nd.idx for nd in graph.nodes if nd.primitive == "dot_general"
+             and graph.tensors[nd.outvars[0]].shape[-1] == cfg.vocab_size}
+    (waste,) = report.waste_findings
+    assert waste.wasteful_side == "A" and heads & set(waste.nodes_a)
+
+
+# -- no path that hides the device ---------------------------------------------
+
+def test_pallas_attention_is_refused_on_the_cached_path(engine):
+    """The Pallas kernel has no cached form: serving with it (every served
+    step attends over a KV cache) is refused when the engine is built, and
+    a cached call outside the engine raises instead of running XLA."""
+    cfg, eng = engine
+    with pytest.raises(ValueError, match="pallas"):
+        ServeEngine(cfg, eng.params, ecfg=_small_ecfg(attn_impl="pallas"))
+    tokens = np.zeros((1, 8), np.int32)
+    with pytest.raises(ValueError, match="pallas"):
+        tf.prefill(cfg, eng.params, tokens, max_len=16, attn_impl="pallas")
+
+
+def test_launch_serve_fails_when_the_asked_audit_is_unavailable(monkeypatch,
+                                                               capsys):
+    from repro.launch import serve
+
+    def boom(self, **kw):
+        raise SimulatedCrash("profiler unavailable")
+
+    monkeypatch.setattr(ServeEngine, "energy_report", boom)
+    argv = ["--smoke", "--requests", "2", "--prompt-len", "4",
+            "--max-new", "2", "--batch-size", "2"]
+    assert serve.main(argv) == 0               # no audit asked: serving ok
+    assert serve.main(argv + ["--energy-audit"]) == 1
+    assert "energy audit unavailable" in capsys.readouterr().err
+
+
+def test_chip_smoke_refuses_a_host_without_a_tpu(capsys, monkeypatch,
+                                                 tmp_path):
+    monkeypatch.setenv("TPU_LOG_DIR", str(tmp_path))   # main() sets a default
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    assert smoke.main([]) != 0
+    out = capsys.readouterr()
+    assert "'cpu'" in out.err
+    assert out.out == ""                        # no result line, no work
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    """The entry points' cache: $JAX_COMPILATION_CACHE_DIR when set, else
+    one fixed directory in the checkout (never a temporary name)."""
+    from repro import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.cache_dir() == os.path.realpath(
+        os.path.join(REPO, ".jax_cache"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.cache_dir() == "/elsewhere/cache"

@@ -19,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Mapping, Sequence
 
-from repro.core.graph import OpGraph, OpNode
+from repro.core.graph import COLLECTIVE_PRIMS, OpGraph, OpNode
 
 # The closed root-cause taxonomy.  Everything downstream — report
 # serialization, golden baselines (repro.testing.baselines), and the
@@ -47,9 +47,7 @@ DIAGNOSIS_SUBKINDS = (
 # Primitive families used to refine a coarse kind into a subkind.  Closed
 # world by design: these mirror what the mutation taxonomy can plant (and
 # what the inverse rewrites can remove), not everything XLA can emit.
-_COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pbroadcast", "all_reduce", "all_gather", "all_to_all",
-    "ppermute", "reduce_scatter", "shard_map", "pmin", "pmax"})
+_COLLECTIVE_PRIMS = COLLECTIVE_PRIMS | {"shard_map"}
 _CONTRACTION_PRIMS = ("dot_general", "conv_general_dilated")
 _ELEMENTWISE_PRIMS = frozenset({
     "add", "sub", "mul", "div", "neg", "exp", "log", "log1p", "expm1",

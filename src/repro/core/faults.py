@@ -155,8 +155,19 @@ class FaultyStore:
         self._track_stale = any(s.kind == "stale_manifest" for s in plan.specs)
         self._prior_manifests: dict[str, dict] = {}
 
-    # Delegate everything not explicitly intercepted (readonly, counters,
-    # root, uri, bulk(), retry, ...) so the wrapper is drop-in.
+    # The Store protocol's data members are real properties: isinstance()
+    # against a runtime protocol looks them up on the class, past
+    # __getattr__.
+    @property
+    def readonly(self) -> bool:
+        return self.inner.readonly
+
+    @property
+    def counters(self) -> dict[str, int]:
+        return self.inner.counters
+
+    # Delegate everything else not explicitly intercepted (root, uri,
+    # bulk(), retry, ...) so the wrapper is drop-in.
     def __getattr__(self, name):
         return getattr(self.inner, name)
 

@@ -33,6 +33,15 @@ _INLINE_PRIMITIVES = ("pjit", "jit", "closed_call", "custom_jvp_call",
                       "custom_vjp_call_jaxpr", "shard_map")
 
 
+# Collective primitives as traced jaxprs bind them, inside shard_map bodies
+# too: jax 0.4 binds a psum there as psum2 + pbroadcast, jax 0.9 as
+# psum_invariant + pvary.
+COLLECTIVE_PRIMS = frozenset({
+    "psum", "psum2", "psum_invariant", "pbroadcast", "pvary", "pmean",
+    "pmin", "pmax", "all_gather", "all_gather_invariant", "all_to_all",
+    "ppermute", "psum_scatter", "reduce_scatter"})
+
+
 def _nested_jaxpr(eqn) -> ClosedJaxpr | None:
     """Return the callee jaxpr of a call-like equation, if any."""
     p = eqn.params

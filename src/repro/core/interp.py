@@ -38,7 +38,7 @@ import jax
 import numpy as np
 from jax._src.core import ClosedJaxpr, Literal
 
-from repro.core.graph import OpGraph
+from repro.core.graph import COLLECTIVE_PRIMS, OpGraph
 
 
 @dataclasses.dataclass
@@ -67,12 +67,6 @@ def _bind(eqn, invals):
 # axis has size 1, so each collective is semantically the identity (and
 # axis_index is 0).  Multi-shard interpretation is impossible off-cluster and
 # raises.
-_COLLECTIVES = {"psum", "psum2", "pmax", "pmin", "pmean", "all_gather",
-                "all_to_all", "ppermute", "pbroadcast", "psum_scatter",
-                "reduce_scatter", "psum_invariant", "all_gather_invariant",
-                "pvary"}
-
-
 def _collective_passthrough(eqn, invals, axis_sizes: dict[str, int]):
     name = eqn.primitive.name
     axes = eqn.params.get("axes", eqn.params.get("axis_name", ()))
@@ -234,7 +228,7 @@ class _ExecPlan:
         blocks: dict[int, _BlockExec] = {}
         for fam in block_structure(graph).families:
             lo, hi = fam.start, fam.start + fam.period
-            if any(graph.nodes[i].primitive in _COLLECTIVES
+            if any(graph.nodes[i].primitive in COLLECTIVE_PRIMS
                    or graph.nodes[i].primitive == "axis_index"
                    or self.axes[i] for i in range(lo, hi)):
                 continue
@@ -381,7 +375,7 @@ def run_instrumented(
             invals = [read_fn(v) for v in eqn.invars]
             wall = None
             iters = 0
-            if eqn.primitive.name in _COLLECTIVES or \
+            if eqn.primitive.name in COLLECTIVE_PRIMS or \
                     eqn.primitive.name == "axis_index":
                 out = _collective_passthrough(eqn, invals, axis_sizes)
             elif measure:
@@ -489,7 +483,7 @@ def _run_flat(graph: OpGraph, plan: _ExecPlan, args, *,
         invals = [env[t] if t in env else consts[t] for t in node.invars]
         wall = None
         iters = 0
-        if node.primitive in _COLLECTIVES or node.primitive == "axis_index":
+        if node.primitive in COLLECTIVE_PRIMS or node.primitive == "axis_index":
             out = _collective_passthrough(eqn, invals, plan.axes[idx])
         elif measure:
             out = _bind(eqn, invals)

@@ -41,14 +41,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.graph import COLLECTIVE_PRIMS
 from repro.optimize.engine import (RewriteContext, RewriteRule, bind_eqn,
                                    bind_eqn_with_params, replay_jaxpr)
-
-# collectives as they appear in traced jaxprs on this jax version (shard_map
-# bodies bind psum as psum2 + pbroadcast)
-_COLLECTIVE_BODY_PRIMS = frozenset({
-    "psum", "psum2", "pbroadcast", "all_gather", "all_to_all", "ppermute",
-    "reduce_scatter", "pmin", "pmax"})
 
 
 def _scalar(x, ctx: "RewriteContext | None" = None) -> float | None:
@@ -205,9 +200,9 @@ class DropIdentityCollective(Rewrite):
             return None
         body_jaxpr = body.jaxpr if hasattr(body, "jaxpr") else body
         prims = {e.primitive.name for e in body_jaxpr.eqns}
-        if not prims <= _COLLECTIVE_BODY_PRIMS:
+        if not prims <= COLLECTIVE_PRIMS:
             self.decline(f"shard_map body is not purely collective: "
-                         f"{sorted(prims - _COLLECTIVE_BODY_PRIMS)}")
+                         f"{sorted(prims - COLLECTIVE_PRIMS)}")
             return None
         if len(eqn.outvars) != len(invals) or not self._take():
             return None

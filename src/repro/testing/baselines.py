@@ -336,11 +336,6 @@ class BaselineStore:
         return RecordResult(baseline=baseline, report=report,
                             art_a=art_a, art_b=art_b)
 
-    def record_all(self, cases: Sequence[Case], *,
-                   energy_rtol: float = DEFAULT_ENERGY_RTOL
-                   ) -> dict[str, RecordResult]:
-        return {c.id: self.record(c, energy_rtol=energy_rtol) for c in cases}
-
     # -- check --------------------------------------------------------------
     def _offline_artifacts(self, case: Case):
         idx = self._load_index().get(case.id)
@@ -416,6 +411,3 @@ class BaselineStore:
             sample_seeds=art_a.sample_seeds, energy_rtol=expected.energy_rtol)
         return diff_baselines(expected, actual)
 
-    def check_all(self, cases: Sequence[Case], *, offline: bool = False
-                  ) -> dict[str, list[Drift]]:
-        return {c.id: self.check(c, offline=offline) for c in cases}

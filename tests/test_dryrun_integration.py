@@ -13,15 +13,18 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# XLA's 512-device compile time varies by an order of magnitude across
+# hosts; a fixed deadline flakes tier-1 on slow CI shards.  The budget
+# comes from the environment (override upward on known-slow machines)
+# and exhausting it skips rather than fails — a timeout says nothing
+# about the dryrun contract, only about this host's compile throughput.
+# The test's own time limit stays a minute above the budget.
+DRYRUN_BUDGET_S = float(os.environ.get("MAGNETON_DRYRUN_BUDGET_S", "240"))
+
 
 @pytest.mark.slow
+@pytest.mark.timeout(DRYRUN_BUDGET_S + 60)
 def test_dryrun_cell_compiles_on_512_devices():
-    # XLA's 512-device compile time varies by an order of magnitude across
-    # hosts; a fixed deadline flakes tier-1 on slow CI shards.  The budget
-    # comes from the environment (override upward on known-slow machines)
-    # and exhausting it skips rather than fails — a timeout says nothing
-    # about the dryrun contract, only about this host's compile throughput.
-    budget_s = float(os.environ.get("MAGNETON_DRYRUN_BUDGET_S", "560"))
     with tempfile.TemporaryDirectory() as out:
         env = dict(os.environ,
                    PYTHONPATH=os.path.join(REPO, "src"))
@@ -31,10 +34,11 @@ def test_dryrun_cell_compiles_on_512_devices():
                 [sys.executable, "-m", "repro.launch.dryrun",
                  "--arch", "gpt2-small", "--shape", "decode_32k",
                  "--mesh", "both", "--out", out],
-                env=env, capture_output=True, text=True, timeout=budget_s)
+                env=env, capture_output=True, text=True,
+                timeout=DRYRUN_BUDGET_S)
         except subprocess.TimeoutExpired:
-            pytest.skip(f"dryrun exceeded the {budget_s:g}s compile budget "
-                        "(set MAGNETON_DRYRUN_BUDGET_S to raise it)")
+            pytest.skip(f"dryrun exceeded the {DRYRUN_BUDGET_S:g}s compile "
+                        "budget (set MAGNETON_DRYRUN_BUDGET_S to raise it)")
         assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
         cells = sorted(os.listdir(out))
         assert len(cells) == 2

@@ -34,6 +34,7 @@ from repro.testing.pytest_plugin import assert_no_energy_regression
 from repro.zoo import cases as zoo
 
 COMMITTED_DIR = Path(__file__).parent / "baselines"
+CASE_IDS = [c.id for c in zoo.list_cases()]
 
 
 # ---------------------------------------------------------------------------
@@ -41,35 +42,34 @@ COMMITTED_DIR = Path(__file__).parent / "baselines"
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-def test_all_zoo_baselines_replay_offline_with_zero_drift(golden, monkeypatch):
+@pytest.mark.parametrize("cid", CASE_IDS)
+def test_all_zoo_baselines_replay_offline_with_zero_drift(cid, golden,
+                                                          monkeypatch):
     """Every recorded case re-compares bit-identically from its golden
     artifacts — with the instrumented interpreter provably never invoked."""
-    golden["records"].record_all()            # lazy fixture: force full zoo
+    golden["records"][cid]                    # lazy fixture: record the case
     def forbid(*a, **k):
         raise AssertionError("offline replay executed a candidate")
 
     monkeypatch.setattr(interp, "run_instrumented", forbid)
     store = BaselineStore(golden["root"])     # fresh store: disk only
-    drifts = store.check_all(zoo.list_cases(), offline=True)
-    bad = {cid: [str(d) for d in ds] for cid, ds in drifts.items() if ds}
-    assert not bad, f"golden replay drifted: {json.dumps(bad, indent=2)}"
+    drifts = store.check(zoo.get_case(cid), offline=True)
+    assert not drifts, "golden replay drifted: " + json.dumps(
+        [str(d) for d in drifts], indent=2)
 
 
 @pytest.mark.slow
-def test_committed_baselines_match_fresh_record(golden):
+@pytest.mark.parametrize("cid", CASE_IDS)
+def test_committed_baselines_match_fresh_record(cid, golden):
     """The expectations committed under tests/baselines/ are what recording
     produces today — i.e. the detector has not drifted since they were
     blessed.  A legitimate behavior change re-records via
     `python -m repro.cli baseline record`."""
-    problems = []
-    for case in zoo.list_cases():
-        path = COMMITTED_DIR / f"{case.id}.json"
-        if not path.exists():
-            problems.append(f"{case.id}: no committed baseline at {path}")
-            continue
-        committed = Baseline.from_json(path.read_text())
-        fresh = golden["records"][case.id]["baseline"]
-        problems.extend(str(d) for d in diff_baselines(committed, fresh))
+    path = COMMITTED_DIR / f"{cid}.json"
+    assert path.exists(), f"{cid}: no committed baseline at {path}"
+    committed = Baseline.from_json(path.read_text())
+    fresh = golden["records"][cid]["baseline"]
+    problems = [str(d) for d in diff_baselines(committed, fresh)]
     assert not problems, "committed baselines drifted:\n  " + \
         "\n  ".join(problems)
 
@@ -328,9 +328,10 @@ def _cli(tmp_path, *argv):
     env["MAGNETON_STORE"] = str(tmp_path / "store")
     return subprocess.run([sys.executable, "-m", "repro.cli", *argv],
                           capture_output=True, text=True, env=env,
-                          cwd=root, timeout=600)
+                          cwd=root, timeout=90)
 
 
+# three CLI runs of at most 90 s each stay inside the default 300 s limit
 @pytest.mark.slow
 def test_cli_baseline_record_and_offline_check(tmp_path):
     bdir = tmp_path / "baselines"

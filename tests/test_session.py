@@ -557,9 +557,11 @@ def _cli(tmp_path, *argv):
     env["MAGNETON_STORE"] = str(tmp_path / "store")
     return subprocess.run([sys.executable, "-m", "repro.cli", *argv],
                           capture_output=True, text=True, env=env,
-                          cwd=root, timeout=300)
+                          cwd=root, timeout=90)
 
 
+# six CLI runs of at most 90 s each
+@pytest.mark.timeout(600)
 def test_cli_smoke(tmp_path):
     r = _cli(tmp_path, "cases")
     assert r.returncode == 0, r.stderr

@@ -10,8 +10,9 @@ import pytest
 
 from _hyp import given, settings, st  # skips property tests w/o hypothesis
 
-from repro.core.tensor_match import (TensorMatcher, bijective_pairs,
-                                     signature, signatures_match)
+from repro.core.tensor_match import (TensorMatcher, _dense_spectrum,
+                                     bijective_pairs, signature,
+                                     signatures_match)
 
 
 def _rand(shape, seed):
@@ -104,6 +105,23 @@ def test_matcher_multi_sample_consistency():
 def test_bijective_filter():
     assert bijective_pairs([(0, 0), (0, 1), (2, 2)]) == [(2, 2)]
     assert bijective_pairs([(0, 0), (1, 0)]) == []
+
+
+@pytest.mark.parametrize("shape,rank", [((256, 4096), 256), ((8192, 128), 128),
+                                        ((1, 5000), 1), ((128, 512), 24)])
+def test_dense_spectrum_matches_full_svd(shape, rank):
+    """The Gram-matrix spectrum equals numpy's SVD to sqrt(eps) of the
+    largest singular value, wide or tall, full rank or rank-deficient."""
+    rng = np.random.default_rng(3)
+    m = (rng.standard_normal((shape[0], rank))
+         @ rng.standard_normal((rank, shape[1])))
+    want = np.linalg.svd(m, compute_uv=False)
+    got = _dense_spectrum(m)
+    assert got.shape == want.shape
+    assert np.all(np.diff(got) <= 0)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=4 * np.sqrt(np.finfo(np.float64).eps)
+                               * want[0])
 
 
 def test_large_tensor_fallback():

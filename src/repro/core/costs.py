@@ -319,6 +319,7 @@ _RULES["ppermute"] = _collective_rule(1.0)
 _RULES["psum_scatter"] = _collective_rule(1.0)
 _RULES["pvary"] = _view_rule                     # replication annotation only
 _RULES["pbroadcast"] = _view_rule                # replication annotation only
+_RULES["layout_constraint"] = _view_rule         # layout annotation only
 
 
 # --- higher-order ------------------------------------------------------------

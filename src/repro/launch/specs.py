@@ -219,15 +219,13 @@ def superblock_segment(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
     cache_args: tuple = ()
     cache_shard: tuple = ()
     if shape.kind in ("decode", "prefill"):
+        # a stack of one superblock: the layer writes into it at index 0
         cache_one = jax.tree_util.tree_map(
-            lambda s: _sds(s.shape[1:], s.dtype),
+            lambda s: _sds((1,) + s.shape[1:], s.dtype),
             tf.abstract_cache(cfg, B, shape.seq_len))
-        axes_one = jax.tree_util.tree_map(
-            lambda ax: ax[1:], tf.cache_logical_axes(cfg),
-            is_leaf=lambda v: isinstance(v, tuple))
         cshard_one = jax.tree_util.tree_map(
             lambda leaf, ax: GLOBAL_RULES.sharding(mesh, ax, leaf.shape),
-            cache_one, axes_one)
+            cache_one, tf.cache_logical_axes(cfg))
         cache_args = (cache_one,)
         cache_shard = (cshard_one,)
 
@@ -263,6 +261,7 @@ def superblock_segment(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
                                    and len(extra) > idx) else None
             out, nc, aux = tf.superblock_apply(
                 cfg, p, x, positions, mesh=mesh, cache=cache,
+                layer=jnp.int32(0),
                 cache_pos=(positions[0, 0] if shape.kind == "decode"
                            else jnp.int32(0)),
                 image_embeds=image, decode=(shape.kind == "decode"),

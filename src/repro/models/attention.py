@@ -13,6 +13,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig
@@ -185,6 +186,52 @@ def _chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float,
 
 
 # ---------------------------------------------------------------------------
+# the stacked KV cache of the cached path (GQA and MLA)
+# ---------------------------------------------------------------------------
+
+def _read_layout(ndim: int) -> Layout:
+    """The layout the score and value dots read a stacked (L, B, T, ...,
+    D) cache in: positions and the feature axis minor, so each (layer,
+    batch, head) block of (T, D) is contiguous."""
+    return Layout(major_to_minor=(0, 1) + tuple(range(3, ndim - 1))
+                  + (2, ndim - 1))
+
+
+def cache_write(cache: Cache, layer: jax.Array, cache_pos: jax.Array,
+                mesh: Mesh | None,
+                **new: jax.Array) -> tuple[Cache, list[jax.Array]]:
+    """Write the new positions of one layer into the stacked cache.
+
+    ``cache`` leaves are (L, B, T, ..., D), stacked over the layers; each
+    ``new`` entry is (B, S, ..., D) and lands at ``(layer, :, cache_pos)``,
+    so only S positions are written and the stack is updated in place when
+    it is a scan carry of a donated buffer.  Copying the layer's whole (B,
+    T, ...) slice out and back instead is zoo case c2-cache-copy.
+
+    Unpartitioned (``mesh`` None), the stack is held in the layout the
+    attention reads it in: left free, the TPU compiler lays the scan carry
+    out for the one-position write, and then transposes the whole cache on
+    entry and each layer's slice before its dots.  A partitioned cache is
+    left to the compiler, since the SPMD partitioner replicates the operand
+    of a layout constraint.  Returns the updated stack and, for each
+    ``new`` entry in order, the layer's (B, T, ..., D) view of it to attend
+    over.
+    """
+    out = dict(cache)
+    for name, val in new.items():
+        buf = cache[name]
+        start = (layer, 0, cache_pos) + (0,) * (buf.ndim - 3)
+        out[name] = jax.lax.dynamic_update_slice(
+            buf, val[None].astype(buf.dtype), start)
+        if mesh is None:
+            out[name] = with_layout_constraint(out[name],
+                                               _read_layout(buf.ndim))
+    return out, [jax.lax.dynamic_index_in_dim(out[name], layer, 0,
+                                              keepdims=False)
+                 for name in new]
+
+
+# ---------------------------------------------------------------------------
 # GQA apply
 # ---------------------------------------------------------------------------
 
@@ -199,9 +246,12 @@ def gqa_init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Cache:
 
 def gqa_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
               positions: jax.Array, *, mesh: Mesh | None = None,
-              cache: Cache | None = None, cache_pos: jax.Array | None = None,
+              cache: Cache | None = None, layer: jax.Array | None = None,
+              cache_pos: jax.Array | None = None,
               causal: bool = True, attn_impl: str = "xla") -> tuple[jax.Array, Cache | None]:
-    """x: (B,S,d).  With a cache, S is the new-token count (1 for decode)."""
+    """x: (B,S,d).  With a cache, S is the new-token count (1 for decode):
+    the stacked cache is updated at ``layer`` (see :func:`cache_write`) and
+    returned."""
     hd = cfg.resolved_head_dim
     q = jnp.einsum("bsd,dhk->bshk", x, params["w_q"])
     k = jnp.einsum("bsd,dhk->bshk", x, params["w_k"])
@@ -242,9 +292,8 @@ def gqa_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
                 f"attn_impl={attn_impl!r} has no cached attention path "
                 f"(prefill into a cache, decode); use one of "
                 f"{CACHED_ATTN_IMPLS}")
-        k_all = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, cache_pos, axis=1)
-        v_all = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, cache_pos, axis=1)
-        new_cache = {"k": k_all, "v": v_all}
+        new_cache, (k_all, v_all) = cache_write(cache, layer, cache_pos,
+                                                mesh, k=k, v=v)
         t = k_all.shape[1]
         if attn_impl == "chunked":
             out = _chunked_sdpa(q, k_all, v_all, scale=scale, causal=causal,
@@ -305,7 +354,8 @@ def _mla_q(cfg: ModelConfig, params: ParamTree, x: jax.Array,
 
 def mla_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
               positions: jax.Array, *, mesh: Mesh | None = None,
-              cache: Cache | None = None, cache_pos: jax.Array | None = None,
+              cache: Cache | None = None, layer: jax.Array | None = None,
+              cache_pos: jax.Array | None = None,
               causal: bool = True, attn_impl: str = "xla") -> tuple[jax.Array, Cache | None]:
     hd, r = cfg.resolved_head_dim, cfg.rope_head_dim
     scale = 1.0 / float(np.sqrt(hd + r))
@@ -319,9 +369,9 @@ def mla_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
     new_cache = None
     if cache is not None:
         assert cache_pos is not None
-        c_all = jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv, cache_pos, axis=1)
-        r_all = jax.lax.dynamic_update_slice_in_dim(cache["k_rope"], k_rope, cache_pos, axis=1)
-        new_cache = {"c_kv": c_all, "k_rope": r_all}
+        new_cache, (c_all, r_all) = cache_write(cache, layer, cache_pos,
+                                                mesh, c_kv=c_kv,
+                                                k_rope=k_rope)
         # absorbed decode: project q into the latent space once, attend over
         # the compressed cache, then expand through w_uv. This is the energy
         # win MLA exists for — the cache stays (T, kv_lora + rope) per token.

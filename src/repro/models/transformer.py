@@ -173,33 +173,46 @@ def cache_logical_axes(cfg: ModelConfig) -> ParamTree:
 def layer_apply(cfg: ModelConfig, spec: LayerSpec, params: ParamTree,
                 x: jax.Array, positions: jax.Array, *,
                 mesh: Mesh | None = None, cache: dict | None = None,
-                cache_pos=None, image_embeds: jax.Array | None = None,
+                layer=None, cache_pos=None,
+                image_embeds: jax.Array | None = None,
                 decode: bool = False, attn_impl: str = "xla"):
+    """One layer.  ``cache`` is this layer's entry of the stacked cache
+    (leaves (L, ...)), updated at index ``layer`` and returned: an attention
+    cache gets only its new positions written, a recurrent state or the
+    image K/V its whole (small) slice."""
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     new_cache = None
     if spec.mixer == "attn":
         apply = attn_mod.mla_apply if cfg.use_mla else attn_mod.gqa_apply
         out, new_cache = apply(cfg, params["attn"], h, positions, mesh=mesh,
-                               cache=cache, cache_pos=cache_pos,
+                               cache=cache, layer=layer, cache_pos=cache_pos,
                                causal=cfg.is_causal, attn_impl=attn_impl)
-    elif spec.mixer == "cross_attn":
-        out, new_cache = attn_mod.cross_apply(cfg, params["attn"], h,
-                                              image_embeds, mesh=mesh,
-                                              cache=cache, attn_impl=attn_impl)
-    elif spec.mixer == "mamba":
-        out, new_cache = ssm_mod.mamba_apply(cfg, params["mamba"], h, mesh=mesh,
-                                             cache=cache, decode=decode)
-    elif spec.mixer == "mlstm":
-        out, new_cache = xlstm_mod.mlstm_apply(cfg, params["mlstm"], h,
-                                               mesh=mesh, cache=cache,
-                                               decode=decode)
-    elif spec.mixer == "slstm":
-        out, new_cache = xlstm_mod.slstm_apply(cfg, params["slstm"], h,
-                                               mesh=mesh, cache=cache,
-                                               decode=decode)
     else:
-        raise ValueError(spec.mixer)
+        own = None if cache is None else jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                                   keepdims=False), cache)
+        if spec.mixer == "cross_attn":
+            out, nc = attn_mod.cross_apply(cfg, params["attn"], h,
+                                           image_embeds, mesh=mesh,
+                                           cache=own, attn_impl=attn_impl)
+        elif spec.mixer == "mamba":
+            out, nc = ssm_mod.mamba_apply(cfg, params["mamba"], h, mesh=mesh,
+                                          cache=own, decode=decode)
+        elif spec.mixer == "mlstm":
+            out, nc = xlstm_mod.mlstm_apply(cfg, params["mlstm"], h,
+                                            mesh=mesh, cache=own,
+                                            decode=decode)
+        elif spec.mixer == "slstm":
+            out, nc = xlstm_mod.slstm_apply(cfg, params["slstm"], h,
+                                            mesh=mesh, cache=own,
+                                            decode=decode)
+        else:
+            raise ValueError(spec.mixer)
+        if cache is not None:
+            new_cache = jax.tree_util.tree_map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                    a, n.astype(a.dtype), layer, 0), cache, nc)
     x = x + out
     if mesh is not None:
         x = constrain(x, mesh, ("batch", "seq_sp", None))
@@ -218,14 +231,17 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, params: ParamTree,
 
 def superblock_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
                      positions: jax.Array, *, mesh=None, cache=None,
-                     cache_pos=None, image_embeds=None, decode=False,
-                     attn_impl="xla"):
+                     layer=None, cache_pos=None, image_embeds=None,
+                     decode=False, attn_impl="xla"):
+    """One superblock.  ``cache`` is the whole stacked cache (leaves with a
+    leading superblock axis) and ``layer`` the superblock's index into it."""
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
     for i, spec in enumerate(cfg.block_pattern):
         lc = cache[f"layer{i}"] if cache is not None else None
         x, nc, aux = layer_apply(cfg, spec, params[f"layer{i}"], x, positions,
-                                 mesh=mesh, cache=lc, cache_pos=cache_pos,
+                                 mesh=mesh, cache=lc, layer=layer,
+                                 cache_pos=cache_pos,
                                  image_embeds=image_embeds, decode=decode,
                                  attn_impl=attn_impl)
         aux_total = aux_total + aux
@@ -240,6 +256,11 @@ def stack_apply(cfg: ModelConfig, blocks: ParamTree, x: jax.Array,
                 remat: bool | str = True, attn_impl: str = "xla"):
     """Scan the superblock over the stacked params/caches.
 
+    The stacked caches ride in the scan's carry with the superblock index,
+    so each layer writes only its new entries into the one buffer (in place
+    when the caller donates it) instead of the scan slicing every layer's
+    whole cache out and stacking it back.
+
     remat: False | True ("full": recompute everything in bwd) | "dots"
     (save matmul outputs: no fwd recompute of dots in bwd, so parameter
     all-gathers and the S^2 attention scores are not re-paid — §Perf lever;
@@ -248,16 +269,14 @@ def stack_apply(cfg: ModelConfig, blocks: ParamTree, x: jax.Array,
     use_cache = caches is not None
 
     def body(carry, inp):
-        xc = carry
-        if use_cache:
-            p_i, c_i = inp
-        else:
-            p_i, c_i = inp, None
+        xc, cc = carry if use_cache else (carry, None)
+        i, p_i = inp if use_cache else (None, inp)
         out, nc, aux = superblock_apply(cfg, p_i, xc, positions, mesh=mesh,
-                                        cache=c_i, cache_pos=cache_pos,
+                                        cache=cc, layer=i,
+                                        cache_pos=cache_pos,
                                         image_embeds=image_embeds,
                                         decode=decode, attn_impl=attn_impl)
-        return out, (nc, aux) if use_cache else aux
+        return ((out, nc) if use_cache else out), aux
 
     if remat == "dots":
         body = jax.checkpoint(body,
@@ -265,12 +284,13 @@ def stack_apply(cfg: ModelConfig, blocks: ParamTree, x: jax.Array,
     elif remat:
         body = jax.checkpoint(body)
 
-    xs = (blocks, caches) if use_cache else blocks
-    x, ys = jax.lax.scan(body, x, xs)
     if use_cache:
-        new_caches, auxs = ys
+        layers = jnp.arange(cfg.num_superblocks, dtype=jnp.int32)
+        (x, new_caches), auxs = jax.lax.scan(body, (x, caches),
+                                             (layers, blocks))
     else:
-        new_caches, auxs = None, ys
+        new_caches = None
+        x, auxs = jax.lax.scan(body, x, blocks)
     return x, new_caches, jnp.sum(auxs)
 
 

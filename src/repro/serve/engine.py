@@ -97,8 +97,11 @@ class ServeEngine:
             cfg, mesh, max_len=self.ecfg.max_len,
             attn_impl=self.ecfg.attn_impl)
         self._prefill = jax.jit(self._prefill_fn)
+        # the cache is donated: each step writes its new position into the
+        # buffer it was given, and the caller's cache is consumed
         self._decode = jax.jit(make_decode_step(cfg, mesh,
-                                                attn_impl=self.ecfg.attn_impl))
+                                                attn_impl=self.ecfg.attn_impl),
+                               donate_argnums=(1,))
         self.stats = {"prefill_calls": 0, "decode_calls": 0,
                       "tokens_generated": 0, "prefill_s": 0.0, "decode_s": 0.0,
                       # audit-health counters (the audit error boundary)

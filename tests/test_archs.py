@@ -26,31 +26,49 @@ def test_forward_shapes_and_finite(arch, arch_state):
     assert bool(jnp.isfinite(aux))
 
 
+@pytest.mark.parametrize("steps", [1, 3])
 @pytest.mark.parametrize("arch", [a for a in ARCHS
                                   if configs.get_config(a).is_causal])
-def test_prefill_decode_parity(arch, arch_state):
-    """decode_step(prefill(prompt)) logits == forward(prompt+token) logits."""
+def test_prefill_decode_parity(arch, steps, arch_state):
+    """Each of ``steps`` decode steps after prefill(prompt) gives the logits
+    of forward over the same prefix.  From the second step on, a cache
+    write that lands at the wrong layer or position shows."""
     cfg, params = arch_state(arch)
     key = jax.random.key(3)
     B, S = 1, 8
-    tokens = jax.random.randint(key, (B, S + 1), 0, cfg.vocab_size)
+    tokens = jax.random.randint(key, (B, S + steps), 0, cfg.vocab_size)
     img = None
     if cfg.family == "vlm":
         img = jax.random.normal(key, (B, cfg.num_image_tokens,
                                       cfg.d_model)).astype(cfg.dtype)
-    # full forward over S+1 tokens
+    # full forward over S+steps tokens: causal, so position S+j sees the
+    # prefix the (j+1)-th decode step has seen
     full_logits, _ = T.forward(cfg, params, tokens, image_embeds=img,
                                remat=False)
-    # prefill on S tokens, decode 1
-    _, caches = T.prefill(cfg, params, tokens[:, :S], max_len=S + 1,
+    # prefill on S tokens, then decode one token a step
+    _, caches = T.prefill(cfg, params, tokens[:, :S], max_len=S + steps,
                           image_embeds=img)
-    dec_logits, _ = T.decode_step(cfg, params, caches, tokens[:, S:S + 1],
-                                  jnp.int32(S))
-    a = np.asarray(full_logits[:, -1, :], np.float32)
-    b = np.asarray(dec_logits[:, -1, :], np.float32)
-    # bf16 accumulation differences across the two paths
-    np.testing.assert_allclose(a, b, atol=0.15, rtol=0.15)
-    assert np.argmax(a) == np.argmax(b)
+    for j in range(steps):
+        dec_logits, caches = T.decode_step(cfg, params, caches,
+                                           tokens[:, S + j:S + j + 1],
+                                           jnp.int32(S + j))
+        a = np.asarray(full_logits[:, S + j, :], np.float32)
+        b = np.asarray(dec_logits[:, -1, :], np.float32)
+        # bf16 accumulation differences across the two paths
+        np.testing.assert_allclose(a, b, atol=0.15, rtol=0.15,
+                                   err_msg=f"decode step {j}")
+        assert np.argmax(a) == np.argmax(b), f"decode step {j}"
+    # every decode write landed where prefill over the same tokens puts it:
+    # a wrong layer or position leaves a slot empty or overwritten, which
+    # near-uniform attention at init hides from the logits
+    _, want = T.prefill(cfg, params, tokens, max_len=S + steps,
+                        image_embeds=img)
+    for (path, got), exp in zip(
+            jax.tree_util.tree_leaves_with_path(caches),
+            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(exp, np.float32),
+            atol=0.15, rtol=0.15, err_msg=jax.tree_util.keystr(path))
 
 
 @pytest.mark.parametrize("arch", ARCHS)

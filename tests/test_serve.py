@@ -58,6 +58,52 @@ def test_greedy_determinism(engine):
     assert a.generated == b.generated
 
 
+# -- the KV cache is updated in place -------------------------------------------
+
+def test_decode_step_writes_only_the_new_position():
+    """The lowered decode step (before XLA's passes, so the same on every
+    backend) writes each attention cache one position at a time: no
+    dynamic_update_slice takes a whole layer's (B, T, KV, D) cache as its
+    update, as a scan that collects each layer's cache would."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.serve.serve_step import make_decode_step
+    cfg = configs.get_config("phi3-medium-14b").reduced()
+    B, T = 3, 24
+    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    params = jax.eval_shape(lambda: tf.model_init(cfg, jax.random.key(0)))
+    text = jax.jit(make_decode_step(cfg, None)).lower(
+        params, tf.abstract_cache(cfg, B, T),
+        jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    updates = re.findall(r"stablehlo\.dynamic_update_slice .*?: \(tensor<"
+                         r"([0-9x]+)x\w+>, tensor<([0-9x]+)x\w+>", text)
+    stacked = f"{cfg.num_superblocks}x{B}x{T}x{kv}x{hd}"
+    into_cache = [upd for buf, upd in updates if buf == stacked]
+    assert len(into_cache) == 2 * len(cfg.block_pattern)      # K and V
+    assert set(into_cache) == {f"1x{B}x1x{kv}x{hd}"}
+    whole_layer = {f"1x{B}x{T}x{kv}x{hd}", f"{B}x{T}x{kv}x{hd}"}
+    assert not whole_layer & {upd for _, upd in updates}
+
+
+def test_decode_step_donates_its_cache(engine):
+    """The engine's jitted decode step aliases its cache argument to its
+    output cache, and the cache handed to a step is consumed by it."""
+    import jax.numpy as jnp
+    cfg, eng = engine
+    _, caches = eng._prefill(eng.params, jnp.zeros((2, 8), jnp.int32), None)
+    cache_bytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(caches))
+    tok, pos = jnp.zeros((2, 1), jnp.int32), jnp.int32(8)
+    mem = eng._decode.lower(eng.params, caches, tok,
+                            pos).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    _, new = eng._decode(eng.params, caches, tok, pos)
+    assert all(a.is_deleted() for a in jax.tree_util.tree_leaves(caches))
+    assert not any(a.is_deleted() for a in jax.tree_util.tree_leaves(new))
+
+
 # -- audit error boundary -----------------------------------------------------
 
 def _small_ecfg(**kw):

@@ -1,6 +1,7 @@
 """Compiles for a described TPU v5e: the main-path kernels at gpt2-small
-widths (including a length that is not a multiple of 8) and the jitted
-gpt2-small decode step.
+widths (including a length that is not a multiple of 8), the jitted
+gpt2-small decode step, and the served decode step at phi3-medium's
+published widths.
 
 Nothing runs: the TPU compiler compiles for a chip that is described, not
 attached, and refuses what the chip would refuse (block shapes off the
@@ -11,7 +12,9 @@ compilation cache is off around these compiles: an entry written for a
 described chip cannot be read back without one.
 """
 
+import dataclasses
 import os
+import re
 import signal
 
 import jax
@@ -108,3 +111,36 @@ def test_gpt2_decode_step_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < 16 * 2**30
+
+
+def test_phi3_medium_decode_updates_its_cache_in_place(one_chip):
+    """At phi3-medium's published widths and the benchmark's batch and
+    cache (2 of its layers), the engine's decode step aliases the donated
+    cache and neither copies nor transposes it: its temporaries stay below
+    one layer's K, which slicing a layer's cache out would take."""
+    from repro.serve.engine import EngineConfig, ServeEngine
+    cfg = dataclasses.replace(get_config("phi3-medium-14b"), num_layers=2,
+                              vocab_size=32064, tie_embeddings=False)
+    batch, max_len = 32, 768
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _shape(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: tf.model_init(cfg, jax.random.key(0))))
+    engine = ServeEngine(cfg, params, ecfg=EngineConfig(batch_size=batch,
+                                                        max_len=max_len))
+    caches = on_chip(tf.abstract_cache(cfg, batch, max_len))
+    compiled = engine._decode.lower(
+        params, caches, _shape(one_chip, (batch, 1), jnp.int32),
+        _shape(one_chip, (), jnp.int32)).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(caches))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // (2 * cfg.num_superblocks)
+    layer = f"{batch},{max_len},{cfg.num_kv_heads},{cfg.resolved_head_dim}]"
+    moved = re.findall(r"= bf16\[([0-9,]+\])\S* (?:copy|transpose)\(",
+                       compiled.as_text())
+    assert not [m for m in moved if m.endswith(layer)]

@@ -4,12 +4,16 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell, its configuration and its traffic mix are found by name in
-``BENCHMARK.json``: ``bench/configs/<config>.json`` holds the model's sizes,
-``bench/traffic/<traffic>.json`` the mix, whose ``runner`` names the module
-under ``bench/runners/`` that serves it, ``bench/limits/<workload>.json`` the
-limit of each number that decides ``correct``, and ``bench/metrics/<metric>.py``
-the reader of each per-layer metric.  Adding a cell, a mix, a configuration
-or a metric adds files; it edits none.
+``BENCHMARK.json``: ``bench/configs/<config>.json`` holds the model's
+published keys and names its ``family``, the module
+``bench/configs/<family>.py`` that reads its sizes, lays out its weights,
+sets the program's configuration, counts its FLOPs and holds its plain
+reference; ``bench/traffic/<traffic>.json`` holds the mix, whose ``runner``
+names the module under ``bench/runners/`` that serves it;
+``bench/limits/<workload>.json`` the limit of each number that decides
+``correct``, and ``bench/metrics/<metric>.py`` the reader of each per-layer
+metric.  Adding a cell, a mix, a configuration, a model family or a metric
+adds files; it edits none.
 
 Without a TPU, or with fewer chips than the cell asks for, the command exits
 2 and prints no result.  ``--rehearse`` runs on whatever JAX finds at the
@@ -30,6 +34,7 @@ T_START = time.perf_counter()
 import argparse                    # noqa: E402
 import contextlib                  # noqa: E402
 import dataclasses                 # noqa: E402
+import functools                   # noqa: E402
 import gc                          # noqa: E402
 import importlib.util              # noqa: E402
 import json                        # noqa: E402
@@ -92,10 +97,16 @@ class Run:
     def name(self) -> str:
         return self.cell["name"]
 
+    @functools.cached_property
+    def family(self):
+        """The configuration's model family, ``bench/configs/<family>.py``:
+        its sizes, weight layout, program settings, FLOPs and reference."""
+        return load_module(BENCH / "configs" / f"{self.config['family']}.py")
+
     @property
     def shape(self) -> dict:
         """The configuration's sizes, cut to the rehearsal size if asked."""
-        return self.mod("weights").shape_of(self.config, self.rehearse)
+        return self.family.shape_of(self.config, self.rehearse)
 
     @property
     def run_dir(self) -> Path:
@@ -105,27 +116,19 @@ class Run:
         """The program's ModelConfig of its architecture, at this
         configuration's sizes."""
         from repro.configs.base import get_config
-        s = self.shape
-        return dataclasses.replace(
-            get_config(self.config["program"]["arch"]),
-            num_layers=s["layers"], d_model=s["d_model"],
-            num_heads=s["heads"], num_kv_heads=s["kv_heads"],
-            head_dim=s["head_dim"], d_ff=s["d_ff"], vocab_size=s["vocab"],
-            tie_embeddings=s["tied"], rope_theta=s["rope_theta"],
-            norm_eps=s["norm_eps"], dtype=s["dtype"])
+        return self.family.program_config(
+            get_config(self.config["program"]["arch"]), self.shape)
 
     def make_params(self, cfg):
         """Weights from the seed, checked against the program's layout."""
         from repro.models import transformer as tf
-        weights = self.mod("weights")
-        weights.check_layout(self.shape, tf.model_abstract_params(cfg))
-        return weights.make(self.shape, self.seed,
+        weights, layout = self.mod("weights"), self.family.layout(self.shape)
+        weights.check_layout(layout, tf.model_abstract_params(cfg))
+        return weights.make(layout, self.seed,
                             self.config["initializer_range"])
 
     def reference(self, mode: str = "f32"):
-        ref = load_module(BENCH / "configs" /
-                          f"{self.config['reference']}.py")
-        return ref.Reference(self.shape, mode)
+        return self.family.Reference(self.shape, mode)
 
     def count_compiles(self) -> None:
         import jax

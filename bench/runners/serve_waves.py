@@ -81,7 +81,7 @@ def run(run) -> dict:
     import numpy as np
     from repro.serve.engine import EngineConfig, Request, ServeEngine
 
-    traffic, flops, e2e = run.mod("traffic"), run.mod("flops"), run.mod("e2e")
+    traffic, e2e = run.mod("traffic"), run.mod("e2e")
     mix, shape = run.mix, run.shape
     cfg = run.model_config()
     params = run.make_params(cfg)
@@ -146,8 +146,8 @@ def run(run) -> dict:
         checks["audit_failures"] = (s["audit_failures"] + s["audit_timeouts"]
                                     + s["audit_skipped"] + errors)
         checks["drift_alarms"] = s["audit_alarms"]
-    model_flops = sum(flops.served_request(shape, len(r.prompt),
-                                           len(r.generated))
+    model_flops = sum(run.family.served_request(shape, len(r.prompt),
+                                                len(r.generated))
                       for r in served)
 
     # the program's state goes before the reference runs

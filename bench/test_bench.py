@@ -4,6 +4,7 @@ broken.  Each run here is at the configurations' rehearsal sizes."""
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import subprocess
@@ -28,7 +29,7 @@ def _load(name: str):
 harness = _load("run")
 traffic = _load("traffic")
 e2e = _load("e2e")
-flops = _load("flops")
+decoder = _load("configs/decoder")
 trace_reduce = _load("trace_reduce")
 host_gap = _load("metrics/host_gap_ms.serve")
 
@@ -116,22 +117,21 @@ def test_p95_is_over_every_request_not_over_chunks():
 
 
 def test_sizes_come_from_the_published_keys():
-    weights = _load("weights")
-    shape = weights.shape_of(PHI3)
+    shape = decoder.shape_of(PHI3)
     assert (shape["layers"], shape["d_model"], shape["heads"],
             shape["kv_heads"], shape["head_dim"], shape["d_ff"],
             shape["vocab"], shape["tied"]) == (8, 5120, 40, 10, 128, 17920,
                                                32064, False)
-    small = weights.shape_of(PHI3, rehearse=True)
+    small = decoder.shape_of(PHI3, rehearse=True)
     assert (small["layers"], small["d_model"], small["head_dim"]) == \
         (2, 64, 16)
     with pytest.raises(ValueError):
-        weights.shape_of(dict(PHI3, hidden_act="gelu_new"))
+        decoder.shape_of(dict(PHI3, hidden_act="gelu_new"))
 
 
 def test_model_flops_match_the_layout():
-    shape = _load("weights").shape_of(PHI3)
-    layout = _load("weights").layout(shape)
+    shape = decoder.shape_of(PHI3)
+    layout = decoder.layout(shape)
     layer_params = sum(int(np.prod(a.shape)) for a in
                        [layout["blocks"]["layer0"]["attn"][k] for k in
                         ("w_q", "w_k", "w_v", "w_o")]
@@ -139,10 +139,71 @@ def test_model_flops_match_the_layout():
                           ("w_gate", "w_up", "w_down")])
     head = shape["d_model"] * shape["vocab"]
     # one token, one position: every weight once, no attention pairs but 1
-    one = flops.forward(shape, 1, 1, 1)
+    one = decoder.forward(shape, 1, 1, 1)
     attn = 2 * 2 * shape["layers"] * shape["heads"] * shape["head_dim"]
     assert one == 2 * layer_params + 2 * head + attn
-    assert flops.served_request(shape, 4, 1) == flops.forward(shape, 1, 4, 1)
+    assert decoder.served_request(shape, 4, 1) == \
+        decoder.forward(shape, 1, 4, 1)
+
+
+# What the harness made of phi3-medium before the dense decoder's sizes,
+# layout, FLOPs and reference moved into its family module: the move left
+# every number of the cell as it was.
+PHI3_SHAPE = {"layers": 8, "d_model": 5120, "heads": 40, "kv_heads": 10,
+              "head_dim": 128, "d_ff": 17920, "vocab": 32064, "tied": False,
+              "rope_theta": 10000.0, "norm_eps": 1e-05, "dtype": "bfloat16"}
+PHI3_LAYOUT = {
+    "['blocks']['layer0']['attn']['w_k']": ((8, 5120, 10, 128), "bfloat16"),
+    "['blocks']['layer0']['attn']['w_o']": ((8, 40, 128, 5120), "bfloat16"),
+    "['blocks']['layer0']['attn']['w_q']": ((8, 5120, 40, 128), "bfloat16"),
+    "['blocks']['layer0']['attn']['w_v']": ((8, 5120, 10, 128), "bfloat16"),
+    "['blocks']['layer0']['mlp']['w_down']": ((8, 17920, 5120), "bfloat16"),
+    "['blocks']['layer0']['mlp']['w_gate']": ((8, 5120, 17920), "bfloat16"),
+    "['blocks']['layer0']['mlp']['w_up']": ((8, 5120, 17920), "bfloat16"),
+    "['blocks']['layer0']['norm1']": ((8, 5120), "float32"),
+    "['blocks']['layer0']['norm2']": ((8, 5120), "float32"),
+    "['embed']['lm_head']": ((5120, 32064), "bfloat16"),
+    "['embed']['tok_embed']": ((32064, 5120), "bfloat16"),
+    "['final_norm']": ((5120,), "float32")}
+PHI3_REQUEST_FLOPS = {(59, 16): 409200066560, (200, 64): 1460733870080,
+                      (512, 256): 4314449838080}
+# sha256 of the rehearsal-size weights for SEED (leaves in tree order), and
+# of the reference's float32 logits (f32, fp8) on fixed tokens with std 0.2
+REHEARSAL_WEIGHTS_SHA256 = \
+    "6a71fc7e341a6ad4ab3d1ca4331e84262bf31b25f9dc1e5ed25a42a051861e27"
+REFERENCE_LOGITS_SHA256 = {
+    "f32": "d9685c47a33f8306809219cdb3af438b826c37976e9d60822245c71dee84feee",
+    "fp8": "8e87828d45ba99302285e1287eabd50e5445707d728433e68f29862d5964e91f"}
+
+
+def _sha256(arrays) -> str:
+    h = hashlib.sha256()
+    for a in arrays:
+        h.update(np.asarray(a).tobytes())
+    return h.hexdigest()
+
+
+def test_the_decoder_family_keeps_phi3_mediums_numbers():
+    import jax
+    shape = decoder.shape_of(PHI3)
+    assert shape == PHI3_SHAPE
+    flat = jax.tree_util.tree_flatten_with_path(decoder.layout(shape))[0]
+    assert {jax.tree_util.keystr(p): (tuple(a.shape), str(a.dtype))
+            for p, a in flat} == PHI3_LAYOUT
+    assert {pair: decoder.served_request(shape, *pair)
+            for pair in PHI3_REQUEST_FLOPS} == PHI3_REQUEST_FLOPS
+    run = _run()
+    assert run.family.__file__ == str(BENCH / "configs" / "decoder.py")
+    harness._program_on_path()
+    params = run.make_params(run.model_config())
+    assert _sha256(jax.tree_util.tree_leaves(params)) == \
+        REHEARSAL_WEIGHTS_SHA256
+    run = _run(std=0.2)
+    params = run.make_params(run.model_config())
+    toks = np.random.default_rng(0).integers(0, 256, (2, 24))
+    for mode, digest in REFERENCE_LOGITS_SHA256.items():
+        logits = run.reference(mode).logits(params, toks)
+        assert _sha256([logits]) == digest, mode
 
 
 # -- trace reduction ----------------------------------------------------------
@@ -205,6 +266,25 @@ TINY_CHAT = {"runner": "serve_waves", "batch": 4, "waves_per_cycle": 2,
              "prompt_len": {"median": 12, "sigma": 0.2, "min": 8, "max": 16},
              "output_len": {"median": 6, "sigma": 0.2, "min": 4, "max": 8},
              "max_len": 32, "auditor": None, "check_requests": 4}
+
+
+@pytest.mark.parametrize("qk_norm_in_reference", [True, False],
+                         ids=["reference", "reference_without_qk_norm"])
+def test_a_family_brought_by_files_alone_is_served(qk_norm_in_reference,
+                                                   monkeypatch):
+    """``testdata/qk_norm_decoder.py``, a family no harness file names,
+    served through ``run_cell``: correct with its reference, and not correct
+    once its reference leaves out the q/k norm the program computes."""
+    run = _run(mix=TINY_CHAT, std=0.2)
+    run.config = dict(run.config, family="../testdata/qk_norm_decoder")
+    family = run.family
+    assert run.model_config().qk_norm is True
+    if not qk_norm_in_reference:
+        monkeypatch.setattr(family.Reference, "_project",
+                            family.decoder.Reference._project)
+    result = harness.run_cell(run)
+    assert result["correct"] is qk_norm_in_reference, result["checks"]
+    assert result["attempted"] > 0 and run.outcome["data"]["model_flops"]
 
 
 def test_reference_is_the_programs_model():
@@ -310,6 +390,15 @@ def test_the_auditor_path_rehearses_clean():
 
 
 # -- the command --------------------------------------------------------------
+
+def test_the_bench_tests_run_under_the_repositorys_time_limit(request):
+    conftest = BENCH.parent / "tests" / "conftest.py"
+    for hook in ("pytest_runtest_setup", "pytest_runtest_call",
+                 "pytest_runtest_teardown"):
+        impls = getattr(request.node.ihook, hook).get_hookimpls()
+        assert [i for i in impls if i.function.__code__.co_filename
+                == str(conftest)], hook
+
 
 def test_off_a_tpu_the_command_prints_nothing_and_fails(capsys):
     rc = harness.main(["--workload", CELL, "--seed", "1",

@@ -22,6 +22,23 @@ class LayerSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071) with DeepSeek's keys: the
+    rotary frequencies are blended between the original and ``factor``-times
+    interpolated ones over the dimensions whose wavelengths fall between
+    ``beta_fast`` and ``beta_slow`` rotations of the original context, and
+    the softmax scale is multiplied by ``mscale(factor, mscale_all_dim)**2``
+    (see :func:`repro.models.layers.yarn_inv_freq`)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                    # dense | moe | hybrid | ssm | audio | vlm
@@ -35,6 +52,7 @@ class ModelConfig:
 
     # --- attention variants ---
     rope_theta: float = 500000.0
+    rope_scaling: YarnScaling | None = None
     qk_norm: bool = False
     qkv_bias: bool = False
     use_mla: bool = False
@@ -49,7 +67,18 @@ class ModelConfig:
     moe_top_k: int = 0
     moe_num_shared: int = 0
     moe_d_ff: int = 0              # expert hidden dim (0 -> d_ff)
-    capacity_factor: float = 1.25
+    # routing: softmax over every expert, the ``moe_topk_group`` best of
+    # ``moe_n_group`` equal groups (by their best expert) kept, the top
+    # ``moe_top_k`` of those taken; weights renormalised or not, then scaled
+    # (DeepSeek-V2's group_limited_greedy; one group is plain top-k)
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_norm_topk: bool = True
+    moe_routed_scale: float = 1.0
+    # (first, count): the routed experts this device holds, as one rank of
+    # expert parallelism does; None holds all of them
+    moe_experts_held: tuple[int, int] | None = None
+    capacity_factor: float = 1.25          # the mesh EP layer's buffers
     router_aux_loss: float = 0.01
 
     # --- SSM (mamba) ---
@@ -70,6 +99,9 @@ class ModelConfig:
 
     # --- layer stack ---
     block_pattern: tuple[LayerSpec, ...] = (LayerSpec("attn", "dense"),)
+    # leading attention + dense-MLP layers (width d_ff) before the
+    # superblocks, as DeepSeek's first_k_dense_replace
+    first_k_dense: int = 0
 
     # --- numerics / misc ---
     norm_eps: float = 1e-5
@@ -78,6 +110,25 @@ class ModelConfig:
     sub_quadratic: bool = False    # supports long_500k decode
 
     # ------------------------------------------------------------------
+    def __post_init__(self):
+        e = self.moe_num_experts
+        if not e:
+            return
+        g, tg = self.moe_n_group, self.moe_topk_group
+        if e % g or not 1 <= tg <= g or self.moe_top_k > tg * (e // g):
+            raise ValueError(
+                f"{self.name}: {e} experts in {g} groups, top {tg} groups, "
+                f"top {self.moe_top_k} experts do not fit together")
+        first, count = self.held_experts
+        if first < 0 or count < 1 or first + count > e:
+            raise ValueError(f"{self.name}: held experts {first}..."
+                             f"{first + count - 1} not among {e}")
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the routed experts this device holds."""
+        return self.moe_experts_held or (0, self.moe_num_experts)
+
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
@@ -96,9 +147,10 @@ class ModelConfig:
 
     @property
     def num_superblocks(self) -> int:
-        assert self.num_layers % self.period == 0, \
-            f"{self.name}: {self.num_layers} layers not divisible by period {self.period}"
-        return self.num_layers // self.period
+        n = self.num_layers - self.first_k_dense
+        assert n % self.period == 0, \
+            f"{self.name}: {n} layers not divisible by period {self.period}"
+        return n // self.period
 
     @property
     def ssm_d_inner(self) -> int:
@@ -119,8 +171,12 @@ class ModelConfig:
 
     def reduced(self, **overrides) -> "ModelConfig":
         """A small same-family config for CPU smoke tests."""
+        experts = min(self.moe_num_experts, 4)
+        groups = min(self.moe_n_group, 2)
+        lead = min(self.first_k_dense, 1)
         small = dict(
-            num_layers=self.period * 2,
+            num_layers=lead + self.period * 2,
+            first_k_dense=lead,
             d_model=64,
             num_heads=max(4, min(self.num_heads, 4)),
             num_kv_heads=max(2, min(self.num_kv_heads, 2)),
@@ -131,8 +187,13 @@ class ModelConfig:
             q_lora_rank=0,
             rope_head_dim=8 if self.use_mla else 0,
             v_head_dim=16 if self.use_mla else 0,
-            moe_num_experts=min(self.moe_num_experts, 4),
+            moe_num_experts=experts,
             moe_top_k=min(self.moe_top_k, 2),
+            # as many groups as 4 experts allow, still keeping fewer than all
+            moe_n_group=groups,
+            moe_topk_group=(groups if self.moe_topk_group == self.moe_n_group
+                            else min(self.moe_topk_group, groups - 1)),
+            moe_experts_held=None,
             moe_num_shared=min(self.moe_num_shared, 1),
             moe_d_ff=32 if self.moe_num_experts else 0,
             ssm_state_dim=8,

@@ -222,10 +222,10 @@ def superblock_segment(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
         # a stack of one superblock: the layer writes into it at index 0
         cache_one = jax.tree_util.tree_map(
             lambda s: _sds((1,) + s.shape[1:], s.dtype),
-            tf.abstract_cache(cfg, B, shape.seq_len))
+            tf.abstract_cache(cfg, B, shape.seq_len)["blocks"])
         cshard_one = jax.tree_util.tree_map(
             lambda leaf, ax: GLOBAL_RULES.sharding(mesh, ax, leaf.shape),
-            cache_one, tf.cache_logical_axes(cfg))
+            cache_one, tf.cache_logical_axes(cfg)["blocks"])
         cache_args = (cache_one,)
         cache_shard = (cshard_one,)
 

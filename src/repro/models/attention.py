@@ -8,6 +8,7 @@ materialized with repeat (that wasteful twin is paper case c4 in the zoo).
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -17,7 +18,8 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import ParamSpec, ParamTree, rms_norm, rope
+from repro.models.layers import (ParamSpec, ParamTree, rms_norm, rope,
+                                 yarn_mscale)
 from repro.sharding.rules import constrain
 
 Cache = dict[str, jax.Array]
@@ -338,6 +340,20 @@ def mla_init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Cache:
     }
 
 
+# rows of a wave prefilled at a time: the expanded keys and the float32
+# (heads, S, T) scores of one row are large at 128 heads
+MLA_PREFILL_ROWS = 4
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk head dim), times YaRN's mscale(factor, mscale_all_dim)**2."""
+    scale = 1.0 / float(np.sqrt(cfg.resolved_head_dim + cfg.rope_head_dim))
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_q(cfg: ModelConfig, params: ParamTree, x: jax.Array,
            positions: jax.Array) -> tuple[jax.Array, jax.Array]:
     hd, r = cfg.resolved_head_dim, cfg.rope_head_dim
@@ -348,8 +364,59 @@ def _mla_q(cfg: ModelConfig, params: ParamTree, x: jax.Array,
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, params["w_q"])
     q_nope, q_rope = q[..., :hd], q[..., hd:hd + r]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope
+
+
+def _mla_expanded(cfg: ModelConfig, params: ParamTree, x: jax.Array,
+                  positions: jax.Array, c_kv: jax.Array, k_rope: jax.Array,
+                  mask: jax.Array | None) -> jax.Array:
+    """Attention of the queries of ``x`` (B,S,d) with every head's keys and
+    values expanded from the latent ``c_kv`` (B,T,kv_lora) and the shared
+    ``k_rope`` (B,T,rope); returns the output projection (B,S,d)."""
+    q_nope, q_rope = _mla_q(cfg, params, x, positions)
+    k_nope = jnp.einsum("btr,rhk->bthk", c_kv, params["w_uk"])
+    v = jnp.einsum("btr,rhk->bthk", c_kv, params["w_uv"])
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                  k_nope.shape[:3] + (cfg.rope_head_dim,))],
+        axis=-1)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    out = _sdpa(q, k, v, mask, scale=mla_softmax_scale(cfg))
+    return jnp.einsum("bshk,hkd->bsd", out, params["w_o"])
+
+
+def _mla_absorbed(cfg: ModelConfig, params: ParamTree, x: jax.Array,
+                  positions: jax.Array, c_all: jax.Array, r_all: jax.Array,
+                  mask: jax.Array) -> jax.Array:
+    """Absorbed decode: project q into the latent space once, attend over the
+    compressed cache, then expand through w_uv.  This is the energy win MLA
+    exists for — the cache stays (T, kv_lora + rope) per token.  fp32
+    contraction: the absorbed order reassociates the bf16 matmuls, and
+    per-layer rounding would compound through deep stacks."""
+    f32 = jnp.float32
+    q_nope, q_rope = _mla_q(cfg, params, x, positions)
+    q_lat = jnp.einsum("bshk,rhk->bshr", q_nope.astype(f32),
+                       params["w_uk"].astype(f32))
+    scores = (jnp.einsum("bshr,btr->bhst", q_lat, c_all.astype(f32))
+              + jnp.einsum("bshk,btk->bhst", q_rope.astype(f32),
+                           r_all.astype(f32))) * mla_softmax_scale(cfg)
+    scores = jnp.where(mask, scores, -1e30)
+    w = jax.nn.softmax(scores, axis=-1)
+    out_lat = jnp.einsum("bhst,btr->bshr", w, c_all.astype(f32))
+    out = jnp.einsum("bshr,rhk->bshk", out_lat,
+                     params["w_uv"].astype(f32)).astype(x.dtype)
+    return jnp.einsum("bshk,hkd->bsd", out, params["w_o"])
+
+
+def _in_row_blocks(fn, rows: int, *args: jax.Array) -> jax.Array:
+    """``fn`` over blocks of ``rows`` batch rows of ``args`` (each (B, ...)),
+    one block after another; the blocks' outputs joined back to (B, ...)."""
+    b = args[0].shape[0]
+    rows = math.gcd(b, rows)
+    split = [a.reshape((b // rows, rows) + a.shape[1:]) for a in args]
+    out = jax.lax.map(lambda xs: fn(*xs), split)
+    return out.reshape((b,) + out.shape[2:])
 
 
 def mla_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
@@ -357,53 +424,38 @@ def mla_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
               cache: Cache | None = None, layer: jax.Array | None = None,
               cache_pos: jax.Array | None = None,
               causal: bool = True, attn_impl: str = "xla") -> tuple[jax.Array, Cache | None]:
-    hd, r = cfg.resolved_head_dim, cfg.rope_head_dim
-    scale = 1.0 / float(np.sqrt(hd + r))
-    q_nope, q_rope = _mla_q(cfg, params, x, positions)
-
-    c_kv = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"])
-    c_kv = rms_norm(c_kv, params["kv_norm"], cfg.norm_eps)
-    k_rope = rope(jnp.einsum("bsd,dr->bsr", x, params["w_kr"])[:, :, None, :],
-                  positions, cfg.rope_theta)[:, :, 0, :]
-
-    new_cache = None
-    if cache is not None:
+    """x: (B,S,d).  Without a cache, keys and values are expanded from the
+    latent of the S tokens.  With one, the new latents are written into the
+    stacked cache at ``layer`` (see :func:`cache_write`); one new token per
+    row (decode) attends absorbed over the latent cache, a prompt (S > 1)
+    expanded over it, :data:`MLA_PREFILL_ROWS` rows at a time."""
+    with jax.named_scope("mla"):
+        s = x.shape[1]
+        c_kv = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"])
+        c_kv = rms_norm(c_kv, params["kv_norm"], cfg.norm_eps)
+        k_rope = rope(jnp.einsum("bsd,dr->bsr", x,
+                                 params["w_kr"])[:, :, None, :],
+                      positions, cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
+        if cache is None:
+            mask = _causal_mask(s, s, 0) if causal else None
+            return _mla_expanded(cfg, params, x, positions, c_kv, k_rope,
+                                 mask), None
         assert cache_pos is not None
         new_cache, (c_all, r_all) = cache_write(cache, layer, cache_pos,
                                                 mesh, c_kv=c_kv,
                                                 k_rope=k_rope)
-        # absorbed decode: project q into the latent space once, attend over
-        # the compressed cache, then expand through w_uv. This is the energy
-        # win MLA exists for — the cache stays (T, kv_lora + rope) per token.
-        # fp32 contraction: the absorbed order reassociates the bf16 matmuls,
-        # and per-layer rounding would compound through deep stacks.
-        f32 = jnp.float32
-        q_lat = jnp.einsum("bshk,rhk->bshr", q_nope.astype(f32),
-                           params["w_uk"].astype(f32))
-        scores = (jnp.einsum("bshr,btr->bhst", q_lat, c_all.astype(f32))
-                  + jnp.einsum("bshk,btk->bhst", q_rope.astype(f32),
-                               r_all.astype(f32))) * scale
         t = c_all.shape[1]
-        mask = _length_mask(t, cache_pos + x.shape[1])[:, :, 0]   # (1,1,1,T)->(1,1,T)
-        if x.shape[1] > 1 and causal:
-            mask = jnp.logical_and(
-                mask, _causal_mask(x.shape[1], t, cache_pos)[:, :, 0])
-        scores = jnp.where(mask, scores, -1e30)
-        w = jax.nn.softmax(scores, axis=-1)
-        out_lat = jnp.einsum("bhst,btr->bshr", w, c_all.astype(f32))
-        out = jnp.einsum("bshr,rhk->bshk", out_lat,
-                         params["w_uv"].astype(f32)).astype(x.dtype)
-    else:
-        k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, params["w_uk"])
-        v = jnp.einsum("bsr,rhk->bshk", c_kv, params["w_uv"])
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
-                                      k_nope.shape[:3] + (r,))], axis=-1)
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        mask = _causal_mask(x.shape[1], x.shape[1], 0) if causal else None
-        out = _sdpa(q, k, v, mask, scale=scale)
-    y = jnp.einsum("bshk,hkd->bsd", out, params["w_o"])
-    return y, new_cache
+        mask = _length_mask(t, cache_pos + s)             # (1,1,1,1,T)
+        if s > 1 and causal:
+            mask = jnp.logical_and(mask, _causal_mask(s, t, cache_pos))
+        if s == 1:
+            y = _mla_absorbed(cfg, params, x, positions, c_all, r_all,
+                              mask[:, :, 0])
+        else:
+            y = _in_row_blocks(
+                lambda *a: _mla_expanded(cfg, params, *a, mask),
+                MLA_PREFILL_ROWS, x, positions, c_all, r_all)
+        return y, new_cache
 
 
 # ---------------------------------------------------------------------------

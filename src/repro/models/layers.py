@@ -11,6 +11,7 @@ definition serves three consumers:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable
 
 import jax
@@ -109,14 +110,49 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * scale.astype(jnp.float32)).astype(dt)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding; x: (..., seq, heads, head_dim), positions: (..., seq)."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(scale) + 1."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """(dim/2,) float32 inverse frequencies under YaRN (``YarnScaling``), as
+    DeepSeek-V2 computes them: dimensions that turn more than ``beta_fast``
+    times over the original context keep their frequency, those that turn
+    fewer than ``beta_slow`` times are interpolated by ``factor``, and a
+    linear ramp blends the ones between."""
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / yarn.factor
+
+    def dim_of(rotations):      # the dimension that turns this many times
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         scaling=None) -> jax.Array:
+    """Rotary embedding; x: (..., seq, heads, head_dim), positions: (..., seq).
+    ``scaling`` (a ``YarnScaling``) gives YaRN's frequencies and scales the
+    rotation by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     head_dim = x.shape[-1]
     half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        gain = 1.0
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(head_dim, theta, scaling))
+        gain = (yarn_mscale(scaling.factor, scaling.mscale)
+                / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, half)
     cos = jnp.cos(angles)[..., :, None, :]   # (..., seq, 1, half)
     sin = jnp.sin(angles)[..., :, None, :]
+    if gain != 1.0:
+        cos, sin = cos * gain, sin * gain
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1)

@@ -2,8 +2,10 @@
 
 The layer stack is ``cfg.num_superblocks`` repetitions of the
 ``cfg.block_pattern`` superblock, scanned with jax.lax.scan (HLO size O(1) in
-depth) and rematerialized per superblock.  Caches/params are pytrees whose
-leaves carry a leading stack dimension.
+depth) and rematerialized per superblock, after ``cfg.first_k_dense``
+leading dense layers scanned the same way (:func:`segments`).  Params and
+caches hold one pytree per segment, whose leaves carry a leading stack
+dimension.
 """
 
 from __future__ import annotations
@@ -57,16 +59,29 @@ def layer_schema(cfg: ModelConfig, spec: LayerSpec) -> ParamTree:
     return sch
 
 
-def superblock_schema(cfg: ModelConfig) -> ParamTree:
+LEAD = (LayerSpec("attn", "dense"),)
+
+
+def segments(cfg: ModelConfig) -> list[tuple[str, tuple[LayerSpec, ...], int]]:
+    """The stack's scanned segments in order, as (name, pattern, count): the
+    leading dense layers (``lead``) if any, then the superblocks
+    (``blocks``)."""
+    lead = [("lead", LEAD, cfg.first_k_dense)] if cfg.first_k_dense else []
+    return lead + [("blocks", cfg.block_pattern, cfg.num_superblocks)]
+
+
+def superblock_schema(cfg: ModelConfig,
+                      pattern: tuple[LayerSpec, ...] | None = None) -> ParamTree:
     return {f"layer{i}": layer_schema(cfg, spec)
-            for i, spec in enumerate(cfg.block_pattern)}
+            for i, spec in enumerate(pattern or cfg.block_pattern)}
 
 
 def model_schema(cfg: ModelConfig) -> ParamTree:
     sch: ParamTree = {
         "embed": embedding_schema(cfg.vocab_size, cfg.d_model, cfg.dtype,
                                   cfg.tie_embeddings),
-        "blocks": stack_schema(superblock_schema(cfg), cfg.num_superblocks),
+        **{name: stack_schema(superblock_schema(cfg, pattern), n)
+           for name, pattern, n in segments(cfg)},
         "final_norm": ParamSpec((cfg.d_model,), ("embed_act",), init="ones",
                                 dtype="float32"),
     }
@@ -89,11 +104,13 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
     from repro.models.layers import count_schema_params
     total = count_schema_params(model_schema(cfg))
     if active_only and cfg.moe_num_experts:
+        # a token meets k of the E experts, k * held / E of those held here
         e, k = cfg.moe_num_experts, cfg.moe_top_k
         routed = 0
         for spec in cfg.block_pattern:
             if spec.ffn == "moe":
-                routed += 3 * cfg.d_model * cfg.resolved_moe_d_ff * e
+                routed += 3 * cfg.d_model * cfg.resolved_moe_d_ff \
+                    * cfg.held_experts[1]
         routed *= cfg.num_superblocks
         total -= int(routed * (1.0 - k / e))
     return total
@@ -120,21 +137,28 @@ def layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
     raise ValueError(spec.mixer)
 
 
+def _segment_caches(cfg: ModelConfig, batch: int, max_len: int, stacked):
+    """One cache pytree per segment; ``stacked(leaf, n)`` stacks a layer's
+    leaf n deep."""
+    out = {}
+    for name, pattern, n in segments(cfg):
+        one = {f"layer{i}": layer_cache(cfg, spec, batch, max_len)
+               for i, spec in enumerate(pattern)}
+        out[name] = jax.tree_util.tree_map(lambda a: stacked(a, n), one)
+    return out
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> ParamTree:
-    """Decode cache pytree with a leading (num_superblocks,) stack dim."""
-    one = {f"layer{i}": layer_cache(cfg, spec, batch, max_len)
-           for i, spec in enumerate(cfg.block_pattern)}
-    nsb = cfg.num_superblocks
-    return jax.tree_util.tree_map(
-        lambda a: jnp.broadcast_to(a[None], (nsb,) + a.shape).copy(), one)
+    """Decode cache pytree: per segment, leaves with a leading stack dim."""
+    return _segment_caches(
+        cfg, batch, max_len,
+        lambda a, n: jnp.broadcast_to(a[None], (n,) + a.shape).copy())
 
 
 def abstract_cache(cfg: ModelConfig, batch: int, max_len: int) -> ParamTree:
-    one = {f"layer{i}": layer_cache(cfg, spec, batch, max_len)
-           for i, spec in enumerate(cfg.block_pattern)}
-    nsb = cfg.num_superblocks
-    return jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct((nsb,) + a.shape, a.dtype), one)
+    return _segment_caches(
+        cfg, batch, max_len,
+        lambda a, n: jax.ShapeDtypeStruct((n,) + a.shape, a.dtype))
 
 
 def cache_logical_axes(cfg: ModelConfig) -> ParamTree:
@@ -159,10 +183,12 @@ def cache_logical_axes(cfg: ModelConfig) -> ParamTree:
         raise ValueError(mixer)
 
     out: dict = {}
-    for i, spec in enumerate(cfg.block_pattern):
-        lc = layer_cache(cfg, spec, 1, 8)
-        out[f"layer{i}"] = {k: axes_for(k, v.ndim + 1, spec.mixer)
-                            for k, v in lc.items()}
+    for seg, pattern, _ in segments(cfg):
+        out[seg] = {}
+        for i, spec in enumerate(pattern):
+            lc = layer_cache(cfg, spec, 1, 8)
+            out[seg][f"layer{i}"] = {k: axes_for(k, v.ndim + 1, spec.mixer)
+                                     for k, v in lc.items()}
     return out
 
 
@@ -222,7 +248,8 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, params: ParamTree,
         if spec.ffn == "dense":
             out2 = mlp_apply(params["mlp"], h2, mesh=mesh)
         else:
-            out2, aux = moe_mod.moe_apply(cfg, params["moe"], h2, mesh=mesh)
+            out2, aux = moe_mod.moe_apply(cfg, params["moe"], h2, mesh=mesh,
+                                          layer=layer)
         x = x + out2
         if mesh is not None:
             x = constrain(x, mesh, ("batch", "seq_sp", None))
@@ -232,12 +259,13 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, params: ParamTree,
 def superblock_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
                      positions: jax.Array, *, mesh=None, cache=None,
                      layer=None, cache_pos=None, image_embeds=None,
-                     decode=False, attn_impl="xla"):
-    """One superblock.  ``cache`` is the whole stacked cache (leaves with a
-    leading superblock axis) and ``layer`` the superblock's index into it."""
+                     decode=False, attn_impl="xla", pattern=None):
+    """One superblock of ``pattern`` (default ``cfg.block_pattern``).
+    ``cache`` is its segment's whole stacked cache (leaves with a leading
+    superblock axis) and ``layer`` the superblock's index into it."""
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
-    for i, spec in enumerate(cfg.block_pattern):
+    for i, spec in enumerate(pattern or cfg.block_pattern):
         lc = cache[f"layer{i}"] if cache is not None else None
         x, nc, aux = layer_apply(cfg, spec, params[f"layer{i}"], x, positions,
                                  mesh=mesh, cache=lc, layer=layer,
@@ -250,16 +278,23 @@ def superblock_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
     return x, (new_cache or None), aux_total
 
 
-def stack_apply(cfg: ModelConfig, blocks: ParamTree, x: jax.Array,
+def stack_apply(cfg: ModelConfig, params: ParamTree, x: jax.Array,
                 positions: jax.Array, *, mesh=None, caches=None,
                 cache_pos=None, image_embeds=None, decode=False,
                 remat: bool | str = True, attn_impl: str = "xla"):
-    """Scan the superblock over the stacked params/caches.
+    """Scan each segment's superblock over its stacked params/caches, the
+    segments in order (``params`` and ``caches`` hold one entry per
+    segment).
 
     The stacked caches ride in the scan's carry with the superblock index,
     so each layer writes only its new entries into the one buffer (in place
     when the caller donates it) instead of the scan slicing every layer's
-    whole cache out and stacking it back.
+    whole cache out and stacking it back.  Likewise, in the served steps
+    (with caches), the held experts' weights stay whole
+    (:func:`moe.split_expert_stacks`): each layer's grouped matmuls read its
+    experts in the stack.  Without caches the scan slices them per layer, as
+    a gradient through the whole stack would give every layer's backward a
+    gradient the size of the whole stack.
 
     remat: False | True ("full": recompute everything in bwd) | "dots"
     (save matmul outputs: no fwd recompute of dots in bwd, so parameter
@@ -267,31 +302,43 @@ def stack_apply(cfg: ModelConfig, blocks: ParamTree, x: jax.Array,
     costs peak activation memory).
     """
     use_cache = caches is not None
+    new_caches = {} if use_cache else None
+    aux_total = jnp.zeros((), jnp.float32)
+    for name, pattern, n in segments(cfg):
+        scanned, whole = (moe_mod.split_expert_stacks(cfg, params[name], mesh)
+                          if use_cache else (params[name], {}))
+        indexed = use_cache or bool(whole)
 
-    def body(carry, inp):
-        xc, cc = carry if use_cache else (carry, None)
-        i, p_i = inp if use_cache else (None, inp)
-        out, nc, aux = superblock_apply(cfg, p_i, xc, positions, mesh=mesh,
-                                        cache=cc, layer=i,
-                                        cache_pos=cache_pos,
-                                        image_embeds=image_embeds,
-                                        decode=decode, attn_impl=attn_impl)
-        return ((out, nc) if use_cache else out), aux
+        def body(carry, inp, pattern=pattern, whole=whole):
+            xc, cc = carry if use_cache else (carry, None)
+            i, p_i = inp if indexed else (None, inp)
+            for lname, experts in whole.items():
+                p_i = dict(p_i, **{lname: dict(
+                    p_i[lname], moe=dict(p_i[lname]["moe"], **experts))})
+            out, nc, aux = superblock_apply(cfg, p_i, xc, positions,
+                                            mesh=mesh, cache=cc, layer=i,
+                                            cache_pos=cache_pos,
+                                            image_embeds=image_embeds,
+                                            decode=decode,
+                                            attn_impl=attn_impl,
+                                            pattern=pattern)
+            return ((out, nc) if use_cache else out), aux
 
-    if remat == "dots":
-        body = jax.checkpoint(body,
-                              policy=jax.checkpoint_policies.dots_saveable)
-    elif remat:
-        body = jax.checkpoint(body)
+        if remat == "dots":
+            body = jax.checkpoint(
+                body, policy=jax.checkpoint_policies.dots_saveable)
+        elif remat:
+            body = jax.checkpoint(body)
 
-    if use_cache:
-        layers = jnp.arange(cfg.num_superblocks, dtype=jnp.int32)
-        (x, new_caches), auxs = jax.lax.scan(body, (x, caches),
-                                             (layers, blocks))
-    else:
-        new_caches = None
-        x, auxs = jax.lax.scan(body, x, blocks)
-    return x, new_caches, jnp.sum(auxs)
+        xs = (jnp.arange(n, dtype=jnp.int32), scanned) if indexed \
+            else scanned
+        if use_cache:
+            (x, new_caches[name]), auxs = jax.lax.scan(
+                body, (x, caches[name]), xs)
+        else:
+            x, auxs = jax.lax.scan(body, x, xs)
+        aux_total = aux_total + jnp.sum(auxs)
+    return x, new_caches, aux_total
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +358,7 @@ def forward(cfg: ModelConfig, params: ParamTree, tokens: jax.Array | None,
         x = constrain(x, mesh, ("batch", "seq_sp", None))
     s = x.shape[1]
     positions = jnp.broadcast_to(jnp.arange(s)[None], x.shape[:2])
-    x, _, aux = stack_apply(cfg, params["blocks"], x, positions, mesh=mesh,
+    x, _, aux = stack_apply(cfg, params, x, positions, mesh=mesh,
                             image_embeds=image_embeds, remat=remat,
                             attn_impl=attn_impl)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -338,7 +385,7 @@ def prefill(cfg: ModelConfig, params: ParamTree, tokens: jax.Array, *,
     s = x.shape[1]
     caches = init_cache(cfg, tokens.shape[0], max_len)
     positions = jnp.broadcast_to(jnp.arange(s)[None], x.shape[:2])
-    x, new_caches, aux = stack_apply(cfg, params["blocks"], x, positions,
+    x, new_caches, aux = stack_apply(cfg, params, x, positions,
                                      mesh=mesh, caches=caches,
                                      cache_pos=jnp.int32(0),
                                      image_embeds=image_embeds, remat=remat,
@@ -360,7 +407,7 @@ def decode_step(cfg: ModelConfig, params: ParamTree, caches: ParamTree,
     else:
         pos_bc = pos
     positions = jnp.broadcast_to(pos_bc, tokens.shape)
-    x, new_caches, _ = stack_apply(cfg, params["blocks"], x, positions,
+    x, new_caches, _ = stack_apply(cfg, params, x, positions,
                                    mesh=mesh, caches=caches, cache_pos=pos,
                                    decode=True, remat=False,
                                    attn_impl=attn_impl)

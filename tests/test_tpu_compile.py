@@ -1,7 +1,8 @@
 """Compiles for a described TPU v5e: the main-path kernels at gpt2-small
 widths (including a length that is not a multiple of 8), the jitted
-gpt2-small decode step, and the served decode step at phi3-medium's
-published widths.
+gpt2-small decode step, the served decode steps at phi3-medium's and
+DeepSeek-V2's published widths, and the training loss's gradient at
+llama4-scout's.
 
 Nothing runs: the TPU compiler compiles for a chip that is described, not
 attached, and refuses what the chip would refuse (block shapes off the
@@ -144,3 +145,65 @@ def test_phi3_medium_decode_updates_its_cache_in_place(one_chip):
     moved = re.findall(r"= bf16\[([0-9,]+\])\S* (?:copy|transpose)\(",
                        compiled.as_text())
     assert not [m for m in moved if m.endswith(layer)]
+
+
+def test_deepseek_v2_decode_step_compiles_with_grouped_expert_matmuls(
+        one_chip):
+    """At DeepSeek-V2's published widths, the leading dense layer and two
+    MoE layers holding 20 of the 160 experts, with the benchmark's batch and
+    cache: the engine's decode step aliases the donated latent cache, the
+    held experts run as grouped (ragged) matmuls in a TPU kernel, and that
+    kernel reads them in the layer stack: no layer's experts are copied out
+    of it each step."""
+    from repro.serve.engine import EngineConfig, ServeEngine
+    cfg = dataclasses.replace(get_config("deepseek-v2-236b"), num_layers=3,
+                              moe_experts_held=(0, 20))
+    batch, max_len = 64, 1152
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _shape(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(tf.model_abstract_params(cfg))
+    engine = ServeEngine(cfg, params, ecfg=EngineConfig(batch_size=batch,
+                                                        max_len=max_len))
+    caches = on_chip(tf.abstract_cache(cfg, batch, max_len))
+    compiled = engine._decode.lower(
+        params, caches, _shape(one_chip, (batch, 1), jnp.int32),
+        _shape(one_chip, (), jnp.int32)).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(caches))
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    assert _kernel_in_module(compiled)
+    one_layer = re.findall(r"= bf16\[20,(?:5120,1536|1536,5120)\]",
+                           compiled.as_text())
+    assert not one_layer
+
+
+def test_llama4_scout_loss_gradient_slices_each_layers_experts(one_chip):
+    """At llama4-scout's published widths (2 of its layers, vocabulary cut),
+    the training loss's gradient through the held-experts layer compiles for
+    one chip with temporaries below the experts' weights: without a cache
+    the layer scan slices each layer's experts, as a read of the whole stack
+    from every layer would give each layer's backward a gradient the size
+    of the stack, which does not fit."""
+    from repro.train.train_step import TrainConfig, make_loss_fn
+    cfg = dataclasses.replace(get_config("llama4-scout-17b-a16e"),
+                              num_layers=2, vocab_size=32768)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _shape(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(tf.model_abstract_params(cfg))
+    loss = make_loss_fn(cfg, None, TrainConfig(remat=True))
+    batch = {k: _shape(one_chip, (2, 512), jnp.int32)
+             for k in ("tokens", "labels")}
+    compiled = jax.jit(jax.grad(lambda p, b: loss(p, b)[0])).lower(
+        params, batch).compile()
+    experts = sum(a.size * a.dtype.itemsize
+                  for path, a in jax.tree_util.tree_leaves_with_path(params)
+                  if "'w_gate'" in jax.tree_util.keystr(path)
+                  or "'w_up'" in jax.tree_util.keystr(path)
+                  or "'w_down'" in jax.tree_util.keystr(path))
+    assert compiled.memory_analysis().temp_size_in_bytes < experts

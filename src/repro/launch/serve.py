@@ -1,4 +1,4 @@
-"""Serving launcher: batched generation with the continuous-batching engine.
+"""Serving launcher: batched generation with the wave-batched engine.
 
 Example (CPU smoke):
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b --smoke \

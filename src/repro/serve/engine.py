@@ -1,11 +1,13 @@
-"""Batched serving engine: continuous batching over prefill/decode steps.
+"""Batched serving engine: fixed waves of prefill and decode steps.
 
-A minimal but real engine: requests enter a queue, are prefilled in batches,
-then decoded together with a shared step counter.  Slot management keeps the
-decode batch full (continuous batching); finished sequences free their slot
-for the next queued request.  The engine exposes an optional Magneton energy
-audit of its prompt pass (``energy_report()``) — the paper's profiler as a
-deployment feature.
+A minimal but real engine: ``generate`` splits its requests into waves of
+``batch_size``, prefills each wave in one batch (prompts left-padded to the
+wave's longest) and decodes it with one shared position until every row is
+done.  A finished row keeps its slot until the wave ends; nothing refills
+it.  The greedy tokens stay on the device between steps, and the host reads
+each step's tokens back one step behind, while the device runs the next
+step.  The engine exposes an optional Magneton energy audit of its prompt
+pass (``energy_report()``) — the paper's profiler as a deployment feature.
 
 The audit path sits behind an error boundary (:meth:`ServeEngine.audit`):
 a watchdog thread bounds how long an audit may run, every failure is
@@ -33,7 +35,8 @@ from repro.audit import AuditConfig, EngineAuditor, RequestClass
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_mod
 from repro.models import transformer as tf
-from repro.serve.serve_step import make_decode_step, make_prefill_step
+from repro.serve.serve_step import (greedy_sample, make_decode_step,
+                                    make_prefill_step)
 
 
 @dataclasses.dataclass
@@ -102,7 +105,14 @@ class ServeEngine:
         self._decode = jax.jit(make_decode_step(cfg, mesh,
                                                 attn_impl=self.ecfg.attn_impl),
                                donate_argnums=(1,))
+        # the greedy pick is a program of its own, so the decode program
+        # stays what make_decode_step compiles to (the benchmark maps its
+        # trace through that); its tokens feed the next step on the device
+        self._sample = jax.jit(greedy_sample)
         self.stats = {"prefill_calls": 0, "decode_calls": 0,
+                      # decode steps enqueued after every row of their wave
+                      # was done: the cost of reading tokens a step behind
+                      "decode_overrun": 0,
                       "tokens_generated": 0, "prefill_s": 0.0, "decode_s": 0.0,
                       # audit-health counters (the audit error boundary)
                       "audit_calls": 0, "audit_ok": 0, "audit_failures": 0,
@@ -128,7 +138,8 @@ class ServeEngine:
 
     # -- batch serving --------------------------------------------------------
     def generate(self, requests: list[Request]) -> list[Request]:
-        """Serve a list of requests with continuous batching."""
+        """Serve a list of requests in fixed waves of ``batch_size``, one
+        wave after another; returns when every request is complete."""
         ecfg = self.ecfg
         queue = list(requests)
         B = min(ecfg.batch_size, len(queue))
@@ -161,39 +172,50 @@ class ServeEngine:
         self.stats["prefill_s"] += dt
         self._observe_audit("prefill", B, plen, latency_s=dt)
 
-        next_tok = np.asarray(jnp.argmax(logits[:, -1, :], -1),
-                              np.int32)[:, None]
-        for i, r in enumerate(wave):
-            r.generated.append(int(next_tok[i, 0]))
-
-        pos = plen
-        max_new = max(r.max_new_tokens for r in wave)
-        for _ in range(max_new - 1):
-            if pos >= ecfg.max_len:
-                break
-            t0 = time.time()
-            logits, caches = self._decode(self.params, caches,
-                                          jnp.asarray(next_tok),
-                                          jnp.int32(pos))
-            dt = time.time() - t0
-            self.stats["decode_calls"] += 1
-            self.stats["decode_s"] += dt
-            self._observe_audit("decode", B, pos, latency_s=dt)
-            next_tok = np.asarray(jnp.argmax(logits[:, -1, :], -1),
-                                  np.int32)[:, None]
-            pos += 1
-            for i, r in enumerate(wave):
-                if r.done or len(r.generated) >= r.max_new_tokens:
-                    r.done = True
-                    continue
-                t = int(next_tok[i, 0])
-                r.generated.append(t)
-                if t == ecfg.eos_id:
-                    r.done = True
-            if all(r.done or len(r.generated) >= r.max_new_tokens
-                   for r in wave):
-                break
+        # Each step's tokens are the next step's input on the device.  The
+        # host enqueues step t+1 before it reads step t's tokens, whose copy
+        # is already under way, so its per-row work overlaps step t+1.  A
+        # row's end at ``eos_id`` is seen a step late: that step is wasted.
+        tok = self._sample(logits)
+        tok.copy_to_host_async()
+        pos, first = plen, True
+        while tok is not None:
+            nxt = None
+            # a row still wants a token past the one in flight
+            if pos < ecfg.max_len and any(
+                    not r.done and len(r.generated) + 1 < r.max_new_tokens
+                    for r in wave):
+                t0 = time.time()
+                logits, caches = self._decode(self.params, caches, tok,
+                                              jnp.int32(pos))
+                dt = time.time() - t0
+                nxt = self._sample(logits)
+                nxt.copy_to_host_async()
+                self.stats["decode_calls"] += 1
+                self.stats["decode_s"] += dt
+                self._observe_audit("decode", B, pos, latency_s=dt)
+                pos += 1
+            live = self._take_tokens(wave, tok, first=first)
+            if nxt is not None and not live:
+                self.stats["decode_overrun"] += 1     # its tokens go unread
+                nxt = None
+            tok, first = nxt, False
         self.stats["tokens_generated"] += sum(len(r.generated) for r in wave)
+
+    def _take_tokens(self, wave: list[Request], tok: jax.Array, *,
+                     first: bool) -> bool:
+        """Read one step's ``(B, 1)`` tokens on the host and append each
+        row's; return whether any row wants more.  A row is done at
+        ``max_new_tokens`` tokens or at ``eos_id``, which the prefill's
+        token (``first``) is not checked against."""
+        eos = self.ecfg.eos_id
+        for r, t in zip(wave, np.asarray(tok)[:, 0].tolist()):
+            if r.done:
+                continue
+            r.generated.append(t)
+            r.done = (len(r.generated) >= r.max_new_tokens
+                      or (t == eos and not first))
+        return not all(r.done for r in wave)
 
     # -- Magneton audit --------------------------------------------------------
     def energy_report(self, *, prompt_len: int = 32, session=None):

@@ -1,4 +1,4 @@
-"""Serve engine: continuous batching, slot reuse, stats, decode parity."""
+"""Serve engine: waves, stats, the device-side token loop, decode parity."""
 
 import importlib.util
 import os
@@ -56,6 +56,167 @@ def test_greedy_determinism(engine):
     eng.generate([a])
     eng.generate([b])
     assert a.generated == b.generated
+
+
+# -- the tokens stay on the device between steps --------------------------------
+
+@pytest.fixture(scope="module")
+def varied(engine):
+    """The module's engine at three times its weights: the greedy tokens
+    then vary from step to step, so an ``eos_id`` can fire mid-wave."""
+    cfg, eng = engine
+    params = jax.tree_util.tree_map(lambda a: a * 3, eng.params)
+    return cfg, ServeEngine(cfg, params, ecfg=EngineConfig(batch_size=2,
+                                                           max_len=48))
+
+
+def _host_synced_wave(eng, wave) -> int:
+    """The oracle: the wave loop as it was when each step's argmax was read
+    back to the host and copied back for the next step.  Returns the
+    decode steps it ran."""
+    import jax.numpy as jnp
+    ecfg = eng.ecfg
+    B = len(wave)
+    plen = max(len(r.prompt) for r in wave)
+    tokens = np.zeros((B, plen), np.int32)
+    for i, r in enumerate(wave):
+        tokens[i, plen - len(r.prompt):] = r.prompt
+    logits, caches = eng._prefill(eng.params, jnp.asarray(tokens), None)
+    next_tok = np.asarray(jnp.argmax(logits[:, -1, :], -1),
+                          np.int32)[:, None]
+    for i, r in enumerate(wave):
+        r.generated.append(int(next_tok[i, 0]))
+    pos, steps = plen, 0
+    for _ in range(max(r.max_new_tokens for r in wave) - 1):
+        if pos >= ecfg.max_len:
+            break
+        logits, caches = eng._decode(eng.params, caches,
+                                     jnp.asarray(next_tok), jnp.int32(pos))
+        steps += 1
+        next_tok = np.asarray(jnp.argmax(logits[:, -1, :], -1),
+                              np.int32)[:, None]
+        pos += 1
+        for i, r in enumerate(wave):
+            if r.done or len(r.generated) >= r.max_new_tokens:
+                r.done = True
+                continue
+            t = int(next_tok[i, 0])
+            r.generated.append(t)
+            if t == ecfg.eos_id:
+                r.done = True
+        if all(r.done or len(r.generated) >= r.max_new_tokens
+               for r in wave):
+            break
+    return steps
+
+
+def _oracle(eng, prompts, max_new):
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=n)
+            for i, (p, n) in enumerate(zip(prompts, max_new))]
+    B = eng.ecfg.batch_size
+    steps = sum(_host_synced_wave(eng, reqs[i:i + B])
+                for i in range(0, len(reqs), B))
+    return [r.generated for r in reqs], steps
+
+
+def _eos_for(generated, row, first):
+    """A token that ``row`` serves at a position of at least ``first`` (and
+    past its prefill token), at no earlier position of its own and nowhere
+    past the prefill token of another row: so it ends ``row`` alone."""
+    others = {t for i, g in enumerate(generated) if i != row for t in g[1:]}
+    for k in range(max(first, 1), len(generated[row]) - 1):
+        t = generated[row][k]
+        if t not in others and t not in generated[row][1:k]:
+            return t
+    raise AssertionError(f"no eos candidate in {generated}")
+
+
+# (prompt lengths, max_new_tokens, the row an eos_id ends and from which
+# position): rows of one wave asking for different lengths, a row asking
+# for one token, no decode step at all, the cache's end, and an eos_id that
+# ends a row while another goes on or ends the wave's last live row.
+PARITY_CASES = {
+    "mixed_lengths": ((8, 8, 8), (6, 3, 4), None),
+    "one_token": ((8, 8), (1, 4), None),
+    "only_the_prefill_token": ((8, 8), (1, 1), None),
+    "cache_end": ((44, 40), (10, 10), None),
+    "eos_ends_one_row": ((8, 8), (10, 10), (0, 2)),
+    "eos_ends_the_last_row": ((8, 8), (3, 10), (1, 3)),
+}
+
+
+@pytest.mark.parametrize("case", list(PARITY_CASES))
+def test_wave_serves_the_host_synced_loops_tokens(varied, monkeypatch, case):
+    """Each request gets the tokens the host-synced greedy loop gives it,
+    in one more decode step at most a wave, and in none more without an
+    ``eos_id``; ``decode_overrun`` counts the extra steps."""
+    cfg, eng = varied
+    lens, max_new, eos_at = PARITY_CASES[case]
+    rng = np.random.default_rng(len(case))
+    prompts = [rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
+               for n in lens]
+    if eos_at is not None:
+        got, _ = _oracle(eng, prompts, (max(max_new),) * len(max_new))
+        monkeypatch.setattr(eng.ecfg, "eos_id", _eos_for(got, *eos_at))
+    want, want_steps = _oracle(eng, prompts, max_new)
+    s0 = dict(eng.stats)
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=n)
+            for i, (p, n) in enumerate(zip(prompts, max_new))]
+    eng.generate(reqs)
+    assert [r.generated for r in reqs] == want
+    overrun = eng.stats["decode_overrun"] - s0["decode_overrun"]
+    steps = eng.stats["decode_calls"] - s0["decode_calls"]
+    assert steps == want_steps + overrun
+    if eos_at is None:
+        assert overrun == 0
+    else:
+        row = eos_at[0]
+        assert want[row][-1] == eng.ecfg.eos_id
+        assert len(want[row]) < max_new[row]
+        # the wave ends a step late only when the eos ends its last row
+        assert overrun == (1 if case == "eos_ends_the_last_row" else 0)
+    assert eng.stats["tokens_generated"] - s0["tokens_generated"] == sum(
+        len(g) for g in want)
+
+
+def test_decode_steps_are_enqueued_before_the_last_tokens_are_read(engine,
+                                                                   monkeypatch):
+    """No host round trip between steps: every decode step's token argument
+    is the device array the sampler gave, and step t+1 is enqueued before
+    step t's tokens are read on the host."""
+    cfg, eng = engine
+    events, made = [], {}
+    decode, sample, take = eng._decode, eng._sample, eng._take_tokens
+
+    def sampler(logits):
+        tok = sample(logits)
+        made[id(tok)] = len(made)
+        return tok
+
+    def decoder(params, caches, tok, pos):
+        assert isinstance(tok, jax.Array) and not isinstance(tok, np.ndarray)
+        events.append(("dispatch", made[id(tok)] + 1))
+        return decode(params, caches, tok, pos)
+
+    def reader(wave, tok, **kw):
+        events.append(("read", made[id(tok)]))
+        return take(wave, tok, **kw)
+
+    monkeypatch.setattr(eng, "_sample", sampler)
+    monkeypatch.setattr(eng, "_decode", decoder)
+    monkeypatch.setattr(eng, "_take_tokens", reader)
+    rng = np.random.default_rng(4)
+    reqs = [Request(rid=i, prompt=rng.integers(0, 256, 8, dtype=np.int32),
+                    max_new_tokens=n) for i, n in enumerate((5, 3, 4))]
+    eng.generate(reqs)
+    assert [len(r.generated) for r in reqs] == [5, 3, 4]
+    dispatched = [k for kind, k in events if kind == "dispatch"]
+    assert len(dispatched) == 4 + 3       # two waves: 4 and 3 decode steps
+    reads = {k: i for i, (kind, k) in enumerate(events) if kind == "read"}
+    for i, (kind, k) in enumerate(events):
+        if kind == "dispatch":
+            # step k enqueued before the tokens of step k - 1 are read
+            assert i < reads[k - 1], events
 
 
 # -- the KV cache is updated in place -------------------------------------------
